@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.config import get_config, reduced
 from repro.models.api import build_model
 from repro.models.layouts import LayoutSpec
@@ -727,6 +728,7 @@ def main(argv=None) -> int:
             print(f"schema: {e}", file=sys.stderr)
         print(f"{args.check}: " + ("INVALID" if errs else "ok"))
         return 1 if errs else 0
+    runtime.enable_compile_cache()
 
     def emit(name, value, derived=""):
         print(f"{name},{value},{derived}", flush=True)

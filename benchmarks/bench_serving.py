@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import runtime
 from repro.config import get_config, reduced
 from repro.models.api import build_decode, build_model
 from repro.models.layouts import LayoutSpec
@@ -267,6 +268,7 @@ def main(argv=None) -> int:
             print(f"schema: {e}", file=sys.stderr)
         print(f"{args.check}: " + ("INVALID" if errs else "ok"))
         return 1 if errs else 0
+    runtime.enable_compile_cache()
 
     def emit(name, value, derived=""):
         print(f"{name},{value},{derived}", flush=True)

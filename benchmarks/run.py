@@ -18,6 +18,8 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default="",
                     help="comma list: fig8,eq,fig6,table1,ablation,serving")
     args = ap.parse_args(argv)
+    from repro import runtime
+    runtime.enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def emit(name: str, value: float, derived: str = "") -> None:

@@ -8,9 +8,11 @@ This kernel is the TPU restatement of the paper's core claim — the decode
 step never touches an O(N) buffer, so it cannot be HBM-bandwidth bound in
 sequence length.
 
-Grid: (B, KV) — fully parallel; no sequential dimension, no scratch.
-The QK^T contraction, masked softmax, and PV contraction are fused in one
-kernel invocation per (batch, kv-head).
+Grid: (B,) — fully parallel; no sequential dimension, no scratch.  One
+program holds every KV head of its slot (a block whose last two dims are
+the whole (KV, D), which Mosaic tiles for any head count) and fuses the
+QK^T contraction, masked softmax and PV contraction per head; the slot's
+``valid_len`` rides in SMEM as a scalar-prefetch operand.
 
 Layout-native extensions (DecodeAPI v3, "KVView"):
 
@@ -34,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -2.3819763e38
 
 
@@ -45,31 +45,34 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, *rest, softcap: float,
         ks_ref, vs_ref, o_ref = rest
     else:
         (o_ref,) = rest
-    q = q_ref[0, 0].astype(jnp.float32)                # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (S, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)             # (S, D)
-    if quant:
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)    # (S, 1) scales
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)
-    vl = vl_ref[0, 0]                                  # scalar int32
+    vl = vl_ref[pl.program_id(0)]                      # scalar int32
+    # one program holds every KV head of its slot: the block's last two
+    # dims are the whole (KV, D), the only form Mosaic tiles for any KV
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32)            # (G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)      # (S, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)      # (S, D)
+        if quant:
+            k = k * ks_ref[0, :, h:h + 1]              # (S, 1) scales
+            v = v * vs_ref[0, :, h:h + 1]
 
-    scale = q.shape[-1] ** -0.5
-    s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)   # (G, S)
-    if softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
-    slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = slot < vl
-    if window > 0:
-        mask = jnp.logical_and(mask, slot >= vl - window)
-    s = jnp.where(mask, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = jnp.where(mask, p, 0.0)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    o_ref[0, 0] = (o / (l + 1e-30)).astype(o_ref.dtype)
+        scale = q.shape[-1] ** -0.5
+        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (G, S)
+        if softcap > 0.0:
+            s = jnp.tanh(s / softcap) * softcap
+        slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = slot < vl
+        if window > 0:
+            mask = jnp.logical_and(mask, slot >= vl - window)
+        s = jnp.where(mask, s, NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        p = jnp.where(mask, p, 0.0)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        o_ref[0, h] = (o / (l + 1e-30)).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -87,34 +90,41 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
     qg = q.reshape(B, KV, G, D)
-    vl = valid_len.reshape(B, 1).astype(jnp.int32)
+    vl = valid_len.reshape(B).astype(jnp.int32)
     quant = k_scale is not None
 
     kernel = functools.partial(_decode_kernel, softcap=softcap,
                                window=window, quant=quant)
+    # index maps receive (b, valid_len_ref): valid_len rides in SMEM
     in_specs = [
-        pl.BlockSpec((1, 1), lambda b, h: (b, 0)),            # valid_len
-        pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),  # q
-        pl.BlockSpec((1, S, 1, D), lambda b, h: (b, 0, h, 0)),  # k
-        pl.BlockSpec((1, S, 1, D), lambda b, h: (b, 0, h, 0)),  # v
+        pl.BlockSpec((1, KV, G, D), lambda b, vl: (b, 0, 0, 0)),   # q
+        pl.BlockSpec((1, S, KV, D), lambda b, vl: (b, 0, 0, 0)),   # k
+        pl.BlockSpec((1, S, KV, D), lambda b, vl: (b, 0, 0, 0)),   # v
     ]
-    args = [vl, qg, k, v]
+    args = [qg, k, v]
     if quant:
+        # (B, S, KV, 1) -> (B, S, KV): the trailing 1 would pad to a
+        # whole 128-lane tile per vector
         in_specs += [
-            pl.BlockSpec((1, S, 1, 1), lambda b, h: (b, 0, h, 0)),  # kscale
-            pl.BlockSpec((1, S, 1, 1), lambda b, h: (b, 0, h, 0)),  # vscale
+            pl.BlockSpec((1, S, KV), lambda b, vl: (b, 0, 0)),     # kscale
+            pl.BlockSpec((1, S, KV), lambda b, vl: (b, 0, 0)),     # vscale
         ]
-        args += [k_scale, v_scale]
+        args += [k_scale.reshape(B, S, KV).astype(jnp.float32),
+                 v_scale.reshape(B, S, KV).astype(jnp.float32)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, KV, G, D), lambda b, vl: (b, 0, 0, 0)),
+    )
     out = pl.pallas_call(
         kernel,
-        grid=(B, KV),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32 if quant
                                        else q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
         name="tconst_decode_attention",
-    )(*args)
+    )(vl, *args)
     return out.reshape(B, H, D).astype(q.dtype)

@@ -21,14 +21,13 @@ inference-critical path.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -2.3819763e38
 INVALID_POS = jnp.iinfo(jnp.int32).max // 2
@@ -48,8 +47,8 @@ def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)                   # (qb, D)
     k = k_ref[0].astype(jnp.float32)                   # (kb, D)
     v = v_ref[0].astype(jnp.float32)                   # (kb, D)
-    qp = qp_ref[0]                                     # (qb,)
-    kp = kp_ref[0]                                     # (kb,)
+    qp = qp_ref[0]                                     # (qb, 1)
+    kp = kp_ref[0]                                     # (1, kb)
 
     scale = q.shape[-1] ** -0.5
     s = jax.lax.dot_general(q * scale, k,
@@ -58,11 +57,11 @@ def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
-    mask = kp[None, :] != INVALID_POS
+    mask = jnp.broadcast_to(kp != INVALID_POS, s.shape)
     if causal:
-        mask = jnp.logical_and(mask, kp[None, :] <= qp[:, None])
+        mask = jnp.logical_and(mask, kp <= qp)
     if window > 0:
-        mask = jnp.logical_and(mask, kp[None, :] > qp[:, None] - window)
+        mask = jnp.logical_and(mask, kp > qp - window)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -101,6 +100,8 @@ def flash_attention_fwd_pallas(
     G = H // KV
     qb = min(block_q, Lq)
     kb = min(block_k, Lk)
+    qb = qb if Lq % qb == 0 else math.gcd(qb, Lq)
+    kb = kb if Lk % kb == 0 else math.gcd(kb, Lk)
     assert Lq % qb == 0 and Lk % kb == 0, (Lq, qb, Lk, kb)
     nq, nk = Lq // qb, Lk // kb
 
@@ -108,8 +109,11 @@ def flash_attention_fwd_pallas(
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kf = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Lk, D)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * H, Lk, D)
-    qpf = jnp.repeat(q_pos, H, axis=0)
-    kpf = jnp.repeat(k_pos, H, axis=0)
+    # positions as (BH, Lq, 1) / (BH, 1, Lk): blocks (1, qb, 1) and
+    # (1, 1, kb) keep legal last-two dims and land query positions on
+    # sublanes, key positions on lanes — the (qb, kb) mask's own layout
+    qpf = jnp.repeat(q_pos, H, axis=0)[:, :, None]
+    kpf = jnp.repeat(k_pos, H, axis=0)[:, None, :]
 
     kernel = functools.partial(_flash_kernel, causal=causal, window=window,
                                softcap=softcap, nk=nk)
@@ -117,8 +121,8 @@ def flash_attention_fwd_pallas(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, qb), lambda b, i, j: (b, i)),        # q_pos
-            pl.BlockSpec((1, kb), lambda b, i, j: (b, j)),        # k_pos
+            pl.BlockSpec((1, qb, 1), lambda b, i, j: (b, i, 0)),  # q_pos
+            pl.BlockSpec((1, 1, kb), lambda b, i, j: (b, 0, j)),  # k_pos
             pl.BlockSpec((1, qb, D), lambda b, i, j: (b, i, 0)),  # q
             pl.BlockSpec((1, kb, D), lambda b, i, j: (b, j, 0)),  # k
             pl.BlockSpec((1, kb, D), lambda b, i, j: (b, j, 0)),  # v
@@ -130,7 +134,7 @@ def flash_attention_fwd_pallas(
             pltpu.VMEM((qb, 1), jnp.float32),      # running denom
             pltpu.VMEM((qb, D), jnp.float32),      # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention_fwd",

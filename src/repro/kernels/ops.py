@@ -23,7 +23,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import runtime
@@ -100,10 +99,10 @@ def _mesh_axes(mesh: Mesh, *, batch: int, heads: Tuple[int, ...]
 
 def _shard_mapped(inner, mesh: Mesh, in_specs, out_specs):
     """shard_map with the conventions used here: dict-pytree operands,
-    replication checking off (per-shard valid_len/page tables are
-    intentionally replicated inside a data shard)."""
-    return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    varying-manual-axes checking off (per-shard valid_len/page tables
+    are intentionally replicated inside a data shard)."""
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -13,10 +13,11 @@ the slot owns.
 Two implementations, one contract (see ``repro.kernels.ops.paged_decode``):
 
 * :func:`paged_decode_attention_pallas` — TPU kernel.  Grid
-  ``(B, KV, pages_per_slot)`` with the page dimension sequential
-  ("arbitrary"): per (batch, kv-head) the kernel runs an online-softmax
-  accumulation over the slot's pages in VMEM scratch (running max /
-  denominator / output).  int8 pools fuse the per-vector dequantisation
+  ``(B, pages_per_slot)`` with the page dimension sequential
+  ("arbitrary"): one page block carries every KV head, and per
+  (slot, kv-head) the kernel runs an online-softmax accumulation over
+  the slot's pages in VMEM scratch (running max / denominator /
+  output).  int8 pools fuse the per-vector dequantisation
   into the QK and PV contractions (1 byte/element off HBM).
 * :func:`paged_decode_attention_xla` — the CPU / interpret fallback: a
   ``lax.scan`` over pages, each iteration gathering ONE page per slot
@@ -39,8 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -2.3819763e38
 
 
@@ -56,7 +55,7 @@ def _paged_kernel(pt_ref, vl_ref, win_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -64,39 +63,43 @@ def _paged_kernel(pt_ref, vl_ref, win_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                 # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)              # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    if quant:
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)     # (page, 1) scales
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)
-
-    scale = q.shape[-1] ** -0.5
-    s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, page)
-    if softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
-    slot = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     vl = vl_ref[b]
     win = win_ref[0]
     weff = jnp.where(win > 0, win, jnp.int32(2 ** 30))
-    mask = jnp.logical_and(slot < vl, slot >= vl - weff)
-    s = jnp.where(mask, s, NEG_INF)
+    # one page block holds every KV head (its last two dims are the whole
+    # (KV, D)); each head runs its own online softmax
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32)             # (G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)       # (page, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if quant:
+            k = k * ks_ref[0, :, h:h + 1]               # (page, 1) scales
+            v = v * vs_ref[0, :, h:h + 1]
 
-    m_prev = m_ref[...]                                  # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(mask, p, 0.0)
-    m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        scale = q.shape[-1] ** -0.5
+        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (G, page)
+        if softcap > 0.0:
+            s = jnp.tanh(s / softcap) * softcap
+        slot = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = jnp.logical_and(slot < vl, slot >= vl - weff)
+        s = jnp.where(mask, s, NEG_INF)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+        m_prev = m_ref[h]                                # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        p = jnp.where(mask, p, 0.0)
+        m_ref[h] = m_new
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       (l_ref[...] + 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    (l_ref[...] + 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_pallas(
@@ -112,7 +115,7 @@ def paged_decode_attention_pallas(
     [0, valid_len) attended.  int8 pools: pass (pool+1, page, KV, 1) f32
     ``k_scale``/``v_scale`` (dequant fused in-kernel).  Returns (B, H, D)."""
     B, H, D = q.shape
-    page, KV = pool_k.shape[1], pool_k.shape[2]
+    n_pages, page, KV = pool_k.shape[0], pool_k.shape[1], pool_k.shape[2]
     pps = page_table.shape[1]
     G = H // KV
     qg = q.reshape(B, KV, G, D)
@@ -122,43 +125,46 @@ def paged_decode_attention_pallas(
 
     kernel = functools.partial(_paged_kernel, page=page, softcap=softcap,
                                quant=quant)
-    # index maps receive (b, h, j, *scalar_prefetch_refs): the page-table
+    # index maps receive (b, j, *scalar_prefetch_refs): the page-table
     # ref picks the physical page for grid step (b, j) — the "in-kernel
     # page-table walk".
     in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, h, j, pt, vl, w: (b, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, pt, vl, w: (pt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, pt, vl, w: (pt[b, j], 0, h, 0)),
+        pl.BlockSpec((1, KV, G, D), lambda b, j, pt, vl, w: (b, 0, 0, 0)),
+        pl.BlockSpec((1, page, KV, D),
+                     lambda b, j, pt, vl, w: (pt[b, j], 0, 0, 0)),
+        pl.BlockSpec((1, page, KV, D),
+                     lambda b, j, pt, vl, w: (pt[b, j], 0, 0, 0)),
     ]
     args = [qg, pool_k, pool_v]
     if quant:
+        # (pool+1, page, KV, 1) -> (pool+1, page, KV): the trailing 1
+        # would pad to a whole 128-lane tile per vector
         in_specs += [
-            pl.BlockSpec((1, page, 1, 1),
-                         lambda b, h, j, pt, vl, w: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, 1),
-                         lambda b, h, j, pt, vl, w: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, page, KV),
+                         lambda b, j, pt, vl, w: (pt[b, j], 0, 0)),
+            pl.BlockSpec((1, page, KV),
+                         lambda b, j, pt, vl, w: (pt[b, j], 0, 0)),
         ]
-        args += [k_scale, v_scale]
+        args += [k_scale.reshape(n_pages, page, KV).astype(jnp.float32),
+                 v_scale.reshape(n_pages, page, KV).astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KV, pps),
+        grid=(B, pps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, j, pt, vl, w: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, D),
+                               lambda b, j, pt, vl, w: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),     # running max
-            pltpu.VMEM((G, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((G, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((KV, G, 1), jnp.float32),     # running max
+            pltpu.VMEM((KV, G, 1), jnp.float32),     # running denominator
+            pltpu.VMEM((KV, G, D), jnp.float32),     # output accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
     )(page_table.astype(jnp.int32), vl, win, *args)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_chunk_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, st_ref):
     xdt = xdt_ref[0, 0, 0].astype(jnp.float32)        # (Q, P)
@@ -75,7 +73,7 @@ def ssd_intra_chunk_pallas(xdt: jax.Array, da: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((B, H, nc, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, nc, P, N), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
         name="ssd_intra_chunk",

@@ -26,9 +26,8 @@ def make_host_mesh():
 def make_decode_mesh(data: int, model: int):
     """(data, model) decode mesh over the first data*model local devices.
 
-    Uses the plain ``jax.sharding.Mesh`` constructor (no AxisType — that
-    API is newer than the pinned jax), so it works on any backend,
-    including a CPU forced to N devices via
+    Uses the plain ``jax.sharding.Mesh`` constructor, so it works on any
+    backend, including a CPU forced to N devices via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     """
     n = data * model

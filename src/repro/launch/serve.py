@@ -88,6 +88,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.config import get_config, reduced
 from repro.launch.mesh import make_decode_mesh
 from repro.models.api import build_decode, build_model
@@ -477,6 +478,13 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tconst-41m")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--dtype", default="", choices=["", "bfloat16",
+                                                    "float32"],
+                    help="activation dtype (default: the config's); "
+                         "float32 also runs matmuls at float32 precision. "
+                         "The greedy solo-run check is bit-exact only in "
+                         "float32: otherwise a batch of another shape may "
+                         "round differently and flip a near-tie")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=64)
@@ -573,6 +581,8 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
+    if args.dtype:
+        cfg = cfg.replace(dtype=args.dtype)
 
     if args.sessions:
         eff_max_len = args.max_len or \
@@ -581,7 +591,18 @@ def main(argv=None) -> int:
         eff_max_len = args.max_len or (args.prompt_len + args.gen + 64)
     validate_layout_args(ap, cfg, args, eff_max_len)
     mesh = build_mesh(ap, cfg, args)
+    runtime.enable_compile_cache()
+    dev = jax.devices()
+    print(f"[serve] platform={dev[0].platform} "
+          f"device_kind={dev[0].device_kind} devices={len(dev)}")
+    # float32 also runs its matmuls in float32: a TPU otherwise runs them
+    # as one bfloat16 pass, rounded as the shape's dot emitter does
+    with jax.default_matmul_precision(
+            "highest" if args.dtype == "float32" else None):
+        return _launch(cfg, args, mesh, eff_max_len)
 
+
+def _launch(cfg, args, mesh, eff_max_len: int) -> int:
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(args.seed))
     if mesh is not None:

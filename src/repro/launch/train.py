@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.config import get_config, reduced
 from repro.data.pipeline import DataConfig, batches
 from repro.models.api import build_model
@@ -44,6 +45,7 @@ def main(argv=None) -> int:
                                                             "text"])
     ap.add_argument("--text-path", default="")
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,4 +1,5 @@
-"""Global runtime flags (kernel routing, interpret mode).
+"""Global runtime flags (kernel routing, interpret mode) and the
+persistent compilation cache that entry points turn on.
 
 Environment overrides (read once at import) let CI exercise the Pallas
 kernels without code changes:
@@ -12,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import pathlib
 
 
 def _env_bool(name: str, default: bool) -> bool:
@@ -31,3 +33,24 @@ flags = Flags(
     use_pallas=_env_bool("REPRO_USE_PALLAS", False),
     pallas_interpret=_env_bool("REPRO_PALLAS_INTERPRET", True),
 )
+
+
+# <checkout>/.jax_cache (git ignores it): a fixed path, since a cache
+# whose directory moves between runs is never found again
+DEFAULT_COMPILE_CACHE = pathlib.Path(__file__).resolve().parents[2] / \
+    ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no other directory is set; otherwise the cache is the fixed
+    ``<checkout>/.jax_cache``.  Called by entry points (``main``s), never
+    on import or from tests."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.config import get_config, reduced
 from repro.models import layouts as LT
@@ -172,3 +173,19 @@ def assert_read_slot_matches_merged(state, slot: int = 0) -> None:
         np.testing.assert_allclose(np.asarray(v), np.asarray(ref),
                                    rtol=0, atol=0,
                                    err_msg=f"read_slot({f}) != oracle")
+
+
+def walk_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and, depth first, of the jaxprs nested
+    in its equations' params (scan / cond / while / pjit bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        stack = list(eqn.params.values())
+        while stack:
+            x = stack.pop()
+            if isinstance(x, ClosedJaxpr):
+                x = x.jaxpr
+            if isinstance(x, Jaxpr):
+                yield from walk_eqns(x)
+            elif isinstance(x, (list, tuple)):
+                stack.extend(x)

@@ -137,26 +137,31 @@ def _make_sessions(cfg, n=5):
                     temperature=0.8, seed=100 + i) for i in range(n)]
 
 
-@pytest.mark.parametrize("policy", ["fifo", "slo"])
-def test_streams_identical_across_policies_through_spills(tconst_setup,
-                                                          policy, request):
+@pytest.fixture(scope="module")
+def spilled_streams(tconst_setup):
+    """policy -> (token streams, spill count) of one oversubscribed run:
+    5 sessions through 2 slots with aggressive preemption, which forces
+    spill/resume cycles under every policy."""
     cfg, api, params = tconst_setup
     decode = build_decode(cfg, LT.LayoutSpec(kind="dense"))
-    # oversubscribed: 5 sessions through 2 slots with aggressive
-    # preemption forces spill/resume cycles under BOTH policies
-    sched = SlotScheduler(decode, params, slots=2, max_len=64,
-                          chunk_size=4, tier_store=TierStore(),
-                          preempt_chunks=1, policy=policy)
-    streams = _drive(sched, _make_sessions(cfg))
-    assert sched.spill_stats["spills"] > 0
-    cache = request.config.cache
-    prior = cache.get("serving_policy/streams", None)
-    mine = [list(t) for t in streams]
-    if prior is None:
-        cache.set("serving_policy/streams", mine)
-    else:
-        assert mine == prior, \
-            "token streams changed with the scheduling policy"
+    runs = {}
+    for policy in ("fifo", "slo"):
+        sched = SlotScheduler(decode, params, slots=2, max_len=64,
+                              chunk_size=4, tier_store=TierStore(),
+                              preempt_chunks=1, policy=policy)
+        runs[policy] = (_drive(sched, _make_sessions(cfg)),
+                        sched.spill_stats["spills"])
+    return runs
+
+
+@pytest.mark.parametrize("policy", ["fifo", "slo"])
+def test_streams_identical_across_policies_through_spills(spilled_streams,
+                                                          policy):
+    streams, spills = spilled_streams[policy]
+    assert spills > 0
+    for other, (other_streams, _) in spilled_streams.items():
+        assert streams == other_streams, \
+            f"token streams changed between policies {policy} and {other}"
 
 
 def test_streams_identical_across_runs_same_seed(tconst_setup):

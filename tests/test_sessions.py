@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import parity
 from repro.config import get_config, reduced
 from repro.core import tconst as TC
 from repro.models import layouts as LT
@@ -324,26 +325,10 @@ def test_undersized_pool_rejects_full_batch_prefill_iff_paged_fields(setup):
 # ---------------------------------------------------------------------------
 
 
-def _subjaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
-        yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
-        yield val
-    elif isinstance(val, (list, tuple)):
-        for v in val:
-            yield from _subjaxprs(v)
-
-
 def _jaxpr_has_host_comms(jaxpr) -> bool:
     bad = ("callback", "infeed", "outfeed", "host")
-    for eqn in jaxpr.eqns:
-        if any(b in eqn.primitive.name for b in bad):
-            return True
-        for v in eqn.params.values():
-            for inner in _subjaxprs(v):
-                if _jaxpr_has_host_comms(inner):
-                    return True
-    return False
+    return any(b in eqn.primitive.name
+               for eqn in parity.walk_eqns(jaxpr) for b in bad)
 
 
 def test_decode_chunk_is_single_dispatch_without_host_comms(setup):
