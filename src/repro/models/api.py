@@ -479,25 +479,31 @@ def decode_chunk(decode: "DecodeAPI", params: Any, state: DecodeState,
     """
     per_slot = key.ndim == 2
 
+    # the named scopes are op metadata only: they label the profile's
+    # ops (step / sample / the freeze of non-live rows) and change no
+    # compiled program
     def body(carry, _):
         state, tok, key = carry
         done = state.bookkeeping["done"]
         live = jnp.logical_and(active, jnp.logical_not(done))
-        logits, new_state = decode.step(params, state, tok)
-        if per_slot:
-            pair = jax.vmap(jax.random.split)(key)       # (B, 2, 2)
-            nxt_key, sub = pair[:, 0], pair[:, 1]
-            key = jnp.where(live[:, None], nxt_key, key)
-        else:
-            key, sub = jax.random.split(key)
-        nxt = sample_tokens(logits, temperature, sub)
-        nxt = jnp.where(live, nxt, tok)
-        new_state = new_state.where_rows(live, state)
-        if eos is not None:
-            hit = jnp.logical_and(live,
-                                  jnp.logical_and(eos >= 0, nxt == eos))
-            new_state = new_state.with_bookkeeping(
-                done=jnp.logical_or(done, hit))
+        with jax.named_scope("decode.step"):
+            logits, new_state = decode.step(params, state, tok)
+        with jax.named_scope("decode.sample"):
+            if per_slot:
+                pair = jax.vmap(jax.random.split)(key)   # (B, 2, 2)
+                nxt_key, sub = pair[:, 0], pair[:, 1]
+                key = jnp.where(live[:, None], nxt_key, key)
+            else:
+                key, sub = jax.random.split(key)
+            nxt = sample_tokens(logits, temperature, sub)
+            nxt = jnp.where(live, nxt, tok)
+        with jax.named_scope("decode.select"):
+            new_state = new_state.where_rows(live, state)
+            if eos is not None:
+                hit = jnp.logical_and(
+                    live, jnp.logical_and(eos >= 0, nxt == eos))
+                new_state = new_state.with_bookkeeping(
+                    done=jnp.logical_or(done, hit))
         return (new_state, nxt, key), nxt
 
     (state, _, key), toks = jax.lax.scan(
@@ -1176,6 +1182,9 @@ class TConstDecode(DecodeAPI):
         axes = TC.CACHE_BATCH_AXES
 
         def factory(kb: int):
+            # each bucket's ops carry its own scope (``tconst.resync.k4``:
+            # the resync of up to 4 rows) in the profile
+            @jax.named_scope(f"tconst.resync.k{kb}")
             def branch(state, idx, sel):
                 bk = state.bookkeeping
                 row_in = {f: take_rows(bk[f], idx, axes[f])

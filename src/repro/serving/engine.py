@@ -69,6 +69,26 @@ class StepStats:
     # cache hit: a known prompt's post-prefill state restored, zero
     # forward compute).  None for non-admit kinds.
     source: Optional[str] = None
+    # ``time.perf_counter()`` when the entry's span began ("admit": when
+    # the scheduler began choosing the session, before its plan).
+    start: Optional[float] = None
+    # "admit" entries: the session admitted; ``queued_s`` is the start
+    # less the session's ``submit_wall`` (first admissions only, not
+    # resumes); ``total_s`` the whole admission, from ``start`` until
+    # the first token is delivered and the slot is live (``seconds``
+    # is its prefill alone).
+    sid: Optional[int] = None
+    queued_s: Optional[float] = None
+    total_s: Optional[float] = None
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """Host span ``serve.<name>`` on the profiler's clock, so it lines up
+    with the device trace of any ``jax.profiler`` session; ``args``
+    (e.g. ``sid``) ride along as the event's stats.  Costs under a
+    microsecond when no profiler runs.  Open it on the host only, never
+    inside a jitted function."""
+    return jax.profiler.TraceAnnotation("serve." + name, **args)
 
 
 def tag_compiled(warm: set, kind: str, sig: Any = None) -> bool:

@@ -19,7 +19,10 @@ Two clocks are recorded side by side:
   whose first chunk triggered a compile, following the PR-4
   ``StepStats.compiled`` convention: the scheduler reports whether each
   tick hit a fresh jit signature and ``on_tokens`` taints the TTFT of
-  sessions whose first token rode a compiling dispatch.
+  sessions whose first token rode a compiling dispatch.  Wall time runs
+  from the session's ``submit_wall`` stamp, and the queue wait ends at
+  its ``admit_wall``: the ``time.perf_counter`` stamps that the
+  scheduler's ``StepStats`` and ``serve.*`` spans share.
 
 Percentiles use the nearest-rank method on sorted samples — no
 interpolation, so a p99 is always a latency some real session saw.
@@ -54,6 +57,8 @@ class SessionRecord:
     slo_itl_chunks: Optional[int] = None
     submit_clock: Optional[int] = None
     first_admit_clock: Optional[int] = None      # first time in a slot
+    submit_wall: Optional[float] = None          # perf_counter at submit
+    queue_wait_seconds: Optional[float] = None   # submit -> first admission
     ttft_chunks: Optional[int] = None            # submit -> first token
     ttft_seconds: Optional[float] = None         # wall; None if compile-hit
     ttft_compiled: bool = False                  # first token hit a compile
@@ -125,7 +130,6 @@ class ServingTelemetry:
     def __init__(self):
         self.records: Dict[int, SessionRecord] = {}
         self.occupancy: List[dict] = []          # one sample per tick
-        self._submit_wall: Dict[int, float] = {}
 
     # -- lifecycle hooks (called by SlotScheduler) ------------------------
     def on_submit(self, session, clock: int) -> None:
@@ -139,12 +143,16 @@ class ServingTelemetry:
             rec.slo_ttft_chunks = session.slo_ttft_chunks
             rec.slo_itl_chunks = session.slo_itl_chunks
             rec.submit_clock = clock
-            self._submit_wall[session.sid] = time.perf_counter()
+            rec.submit_wall = session.submit_wall \
+                if session.submit_wall is not None else time.perf_counter()
 
     def on_admit(self, session, clock: int, source: str) -> None:
         rec = self.records[session.sid]
         if rec.first_admit_clock is None:
             rec.first_admit_clock = clock
+            start = session.admit_wall \
+                if session.admit_wall is not None else time.perf_counter()
+            rec.queue_wait_seconds = start - rec.submit_wall
         if source == "resume":
             rec.resumes += 1
 
@@ -163,7 +171,7 @@ class ServingTelemetry:
             rec.ttft_chunks = (clock - rec.submit_clock
                                if rec.submit_clock is not None else None)
             rec.ttft_compiled = compiled
-            wall = self._submit_wall.get(session.sid)
+            wall = rec.submit_wall
             rec.ttft_seconds = None if (compiled or wall is None) \
                 else time.perf_counter() - wall
         elif rec.last_token_clock is not None:
@@ -199,13 +207,16 @@ class ServingTelemetry:
     # -- aggregation ------------------------------------------------------
     def summary(self) -> dict:
         """Aggregate to the ``BENCH_serving.json`` per-run block: p50/p99
-        TTFT (chunks + warm wall-seconds), inter-token gaps, queue wait,
-        SLO attainment, spill/resume totals, mean pool occupancy."""
+        TTFT (chunks + warm wall-seconds), inter-token gaps, queue wait
+        (chunks + wall-seconds), SLO attainment, spill/resume totals,
+        mean pool occupancy."""
         recs = list(self.records.values())
         ttft_c = [r.ttft_chunks for r in recs if r.ttft_chunks is not None]
         ttft_s = [r.ttft_seconds for r in recs if r.ttft_seconds is not None]
         waits = [r.queue_wait_chunks for r in recs
                  if r.queue_wait_chunks is not None]
+        waits_s = [r.queue_wait_seconds for r in recs
+                   if r.queue_wait_seconds is not None]
         gaps = [g for r in recs for g in r.itl_gaps_chunks]
         slo = [r.slo_ok for r in recs if r.slo_ok is not None]
         ttft_slo = [r.ttft_ok for r in recs if r.ttft_ok is not None]
@@ -224,6 +235,8 @@ class ServingTelemetry:
                            "p99": percentile(gaps, 99)},
             "queue_wait_chunks": {"p50": percentile(waits, 50),
                                   "p99": percentile(waits, 99)},
+            "queue_wait_s": {"p50": percentile(waits_s, 50),
+                             "p99": percentile(waits_s, 99)},
             "slo": {
                 "sessions_with_slo": len(slo),
                 "attainment": (sum(slo) / len(slo)) if slo else None,

@@ -65,7 +65,13 @@ spills as ``kind="spill"``), admissions as ``StepStats(kind="admit")``
 in ``admit_stats`` with ``source`` naming where the slot state came
 from ("cold" / "resume" / "store"); entries whose wall-clock includes a
 one-time jit compile carry ``compiled=True`` so aggregations
-(``benchmarks/bench_inference``) can exclude them.
+(``benchmarks/bench_inference``) can exclude them.  Each entry carries
+its ``start`` on ``time.perf_counter``; an admission's also its
+``sid``, queue wait and whole span (``queued_s``, ``total_s``).  The
+phases of ``step`` are ``serve.*`` host spans (``engine.span``) and
+every jitted program has a stable module name, so a ``jax.profiler``
+trace shows what the host was doing while the device idled
+(``docs/serving.md``, "Tracing").
 
 Two later additions layer policy on top of this mechanism:
 
@@ -102,13 +108,21 @@ import numpy as np
 from repro.models import layouts as LT
 from repro.models.api import (DecodeAPI, decode_chunk, sample_tokens,
                               spec_chunk)
-from repro.serving.engine import StepStats, tag_compiled
+from repro.serving.engine import StepStats, span, tag_compiled
 from repro.serving.metrics import ServingTelemetry
 from repro.serving.policy import FifoPolicy, SchedulingPolicy, get_policy
 from repro.serving.session import Session
 from repro.serving.speculative import Drafter, get_drafter
 from repro.serving.tier_store import (Blob, TierStore, flatten_slot_snapshot,
                                       unflatten_slot_snapshot)
+
+
+def _jit(name: str, fn, **kw):
+    """``jax.jit`` of ``fn`` named ``name``: its program is the module
+    ``jit_<name>`` in a profile, where a partial or a lambda would read
+    ``jit__unknown`` or ``jit__lambda``."""
+    fn.__name__ = name
+    return jax.jit(fn, **kw)
 
 
 class SlotScheduler:
@@ -156,7 +170,8 @@ class SlotScheduler:
                                       vocab=decode.cfg.vocab_size,
                                       max_len=max_len, seed=seed)
             self.drafter = drafter
-            self._spec = jax.jit(functools.partial(spec_chunk, decode))
+            self._spec = _jit("spec_chunk",
+                              functools.partial(spec_chunk, decode))
         # chunked KV-conditioned admission: default rides on the decode
         # protocol (build_decode(prefill_chunk=...)); None = one-shot
         # full-prompt prefill (one compile per distinct prompt length)
@@ -174,9 +189,11 @@ class SlotScheduler:
         dense_decode = dataclasses.replace(decode, layout=LT.DENSE_SPEC)
         self._empty_row = dense_decode.init_state(1, max_len)
         self._prefill_slot = jax.jit(decode.prefill_into_slot)
-        self._chunk = jax.jit(functools.partial(decode_chunk, decode),
-                              static_argnames=("n_steps",))
-        self._clear = jax.jit(lambda st, slot, row: st.with_slot(slot, row))
+        self._chunk = _jit("decode_chunk",
+                           functools.partial(decode_chunk, decode),
+                           static_argnames=("n_steps",))
+        self._clear = _jit("clear_slot",
+                           lambda st, slot, row: st.with_slot(slot, row))
 
         # paged layout: the scheduler owns page assignment.  Start from an
         # all-TRASH table (a real page is writable iff its refcount is 1 —
@@ -196,8 +213,9 @@ class SlotScheduler:
             self.state = self.state.with_bookkeeping(**{LT.PAGE_TABLE: trash})
             self.free_pages = list(range(self.layout.pool_pages))
             self._page_ref = np.zeros((self.layout.pool_pages,), np.int32)
-            self._fork = jax.jit(lambda st, src, dst: dataclasses.replace(
-                st, kv=self.layout.fork_pages(st.kv, src, dst)))
+            self._fork = _jit(
+                "fork_pages", lambda st, src, dst: dataclasses.replace(
+                    st, kv=self.layout.fork_pages(st.kv, src, dst)))
         if self.prefill_chunk is not None and self._paged and \
                 self.prefill_chunk % self.layout.page != 0:
             raise ValueError(
@@ -244,13 +262,17 @@ class SlotScheduler:
         else:
             self._page_axes = {}
         if self.store is not None:
-            self._snap = jax.jit(lambda st, slot: st.snapshot_slot(slot))
-            self._restore = jax.jit(
+            self._snap = _jit("snapshot_slot",
+                              lambda st, slot: st.snapshot_slot(slot))
+            self._restore = _jit(
+                "restore_slot",
                 lambda st, slot, snap: st.restore_slot(slot, snap))
             if self._paged:
-                self._gather_pages = jax.jit(
+                self._gather_pages = _jit(
+                    "gather_pages",
                     lambda st, idx: self.layout.gather_pages(st.kv, idx))
-                self._scatter_pages = jax.jit(
+                self._scatter_pages = _jit(
+                    "scatter_pages",
                     lambda st, idx, contents: dataclasses.replace(
                         st, kv=self.layout.scatter_pages(st.kv, idx,
                                                          contents)))
@@ -310,6 +332,7 @@ class SlotScheduler:
                 f" pages but the paged pool only has "
                 f"{self.layout.pool_pages} — it could never be admitted")
         session.submit_clock = self.clock
+        session.submit_wall = time.perf_counter()
         self.pending.append(session)
         if self.telemetry is not None:
             self.telemetry.on_submit(session, self.clock)
@@ -511,34 +534,35 @@ class SlotScheduler:
         session = self.sessions[slot]
         assert session is not None and not session.done, \
             "can only spill a live session"
-        t0 = time.perf_counter()
-        snap = self._snapshot_slot_host(
-            slot, self._live_pages(session) if self._paged else None)
-        blob = flatten_slot_snapshot(snap, {
-            "kind": "session", "sid": session.sid,
-            "last_token": int(np.asarray(self.last_token[slot]))})
-        key = self._session_key(session)
-        self.store.put(key, blob, pin=True)
-        self.stats.append(StepStats(
-            "spill", time.perf_counter() - t0,
-            tokens=len(session.prompt) + len(session.tokens),
-            compiled=tag_compiled(self._warm, "spill")))
-        session.snap_key = key
-        session.spills += 1
-        session.slot = None
-        # freeze the session's sampling chain at its current position
-        # (= len(session.tokens)) so resume continues the exact stream
-        session.sample_chain = np.asarray(self.slot_keys[slot])
-        self.spill_stats["spills"] += 1
-        self.spill_stats["spilled_bytes"] += blob.nbytes
-        self._release(slot)
-        self.pending.append(session)
-        if self.telemetry is not None:
-            self.telemetry.on_spill(session, self.clock)
+        with span("spill", sid=session.sid):
+            t0 = time.perf_counter()
+            snap = self._snapshot_slot_host(
+                slot, self._live_pages(session) if self._paged else None)
+            blob = flatten_slot_snapshot(snap, {
+                "kind": "session", "sid": session.sid,
+                "last_token": int(np.asarray(self.last_token[slot]))})
+            key = self._session_key(session)
+            self.store.put(key, blob, pin=True)
+            self.stats.append(StepStats(
+                "spill", time.perf_counter() - t0,
+                tokens=len(session.prompt) + len(session.tokens),
+                compiled=tag_compiled(self._warm, "spill")))
+            session.snap_key = key
+            session.spills += 1
+            session.slot = None
+            # freeze the session's sampling chain at its current position
+            # (= len(session.tokens)) so resume continues the exact stream
+            session.sample_chain = np.asarray(self.slot_keys[slot])
+            self.spill_stats["spills"] += 1
+            self.spill_stats["spilled_bytes"] += blob.nbytes
+            self._release(slot)
+            self.pending.append(session)
+            if self.telemetry is not None:
+                self.telemetry.on_spill(session, self.clock)
         return key
 
     def _resume(self, session: Session, slot: int,
-                plan: Dict[str, Any]) -> None:
+                plan: Dict[str, Any], t_plan: float) -> None:
         """Admission path for a spilled session: allocate fresh private
         pages, restore the pinned snapshot into ``slot`` with ONE jitted
         scatter, and unpin.  No prefill, no sampling — the session's
@@ -563,7 +587,8 @@ class SlotScheduler:
             "admit", time.perf_counter() - t0,
             tokens=len(session.prompt) + len(session.tokens),
             compiled=tag_compiled(self._warm, "admit", ("resume",)),
-            forward_tokens=0, source="resume"))
+            forward_tokens=0, source="resume", start=t_plan,
+            sid=session.sid))
         self.store.unpin(session.snap_key)
         session.snap_key = None
         session.resumes += 1
@@ -585,6 +610,7 @@ class SlotScheduler:
             self.drafter.admit(slot, list(session.prompt) + session.tokens)
         if self.telemetry is not None:
             self.telemetry.on_admit(session, self.clock, "resume")
+        self.admit_stats[-1].total_s = time.perf_counter() - t_plan
 
     def _retire_pages(self, retiring: List) -> None:
         """Refcount-0 prefix pages RETIRE into the tier store instead of
@@ -690,53 +716,60 @@ class SlotScheduler:
                 "restorable": restorable, "resume": resume,
                 "admit_key": admit_key, "admit_hit": admit_hit}
 
-    def _admit(self, session: Session, slot: int,
-               plan: Dict[str, Any]) -> None:
+    def _admit(self, session: Session, slot: int, plan: Dict[str, Any],
+               t_plan: float) -> None:
+        """Fill ``slot`` with ``session`` as ``plan`` says; ``t_plan``
+        is when choosing it began (``perf_counter``), the start of its
+        admission."""
+        session.admit_wall = t_plan
         if plan.get("resume"):
-            self._resume(session, slot, plan)
+            with span("resume"):
+                self._resume(session, slot, plan, t_plan)
             return
-        admit_blob = None
-        if plan.get("admit_hit"):
-            # fetch FIRST (nothing else touches the store before this):
-            # None means the entry aged out since planning — the plan's
-            # all-fresh pages make the cold path below still valid
-            admit_blob = self.store.get(plan["admit_key"])
-        mask = None
-        n_resident = 0
-        if self._paged:
-            n_adopt = len(plan["adopted"])
-            readopt = self._fetch_restorable(plan.get("restorable", [])) \
-                if admit_blob is None else []
-            fresh = [self.free_pages.pop()
-                     for _ in range(plan["total"] - n_adopt)]
-            pages = list(plan["adopted"]) + fresh
-            for p in plan["adopted"]:
-                self._page_ref[p] += 1
-            for p in fresh:
-                self._page_ref[p] = 1
-            self._set_table_row(slot, pages)
-            n_rest = len(readopt)
-            if n_rest:
-                # upload retired prefix-page content from the store onto
-                # this slot's fresh pages BEFORE the prefill, so the
-                # chunk loop attends it instead of re-forwarding it
-                self._upload_pages(pages[n_adopt:n_adopt + n_rest],
-                                   readopt)
-            n_resident = n_adopt + n_rest
-            if self.prefix_sharing:
-                # register this prompt's freshly written stable pages so
-                # later sessions can adopt them (adopted ones already
-                # are; re-adopted ones re-enter the map resident)
-                for i, key in enumerate(plan["keys"]):
-                    if key not in self._prefix_map:
-                        self._register(key, pages[i])
-                if n_resident:
-                    # tail-only admission write: resident pages hold the
-                    # identical (content-addressed) KV already — and CoW
-                    # says never write a page with refcount > 1
-                    host_mask = np.ones((self.layout.pages_per_slot,), bool)
-                    host_mask[:n_resident] = False
-                    mask = jnp.asarray(host_mask)
+        with span("admit.plan"):
+            admit_blob = None
+            if plan.get("admit_hit"):
+                # fetch FIRST (nothing else touches the store before this):
+                # None means the entry aged out since planning — the plan's
+                # all-fresh pages make the cold path below still valid
+                admit_blob = self.store.get(plan["admit_key"])
+            mask = None
+            n_resident = 0
+            if self._paged:
+                n_adopt = len(plan["adopted"])
+                readopt = self._fetch_restorable(plan.get("restorable", [])) \
+                    if admit_blob is None else []
+                fresh = [self.free_pages.pop()
+                         for _ in range(plan["total"] - n_adopt)]
+                pages = list(plan["adopted"]) + fresh
+                for p in plan["adopted"]:
+                    self._page_ref[p] += 1
+                for p in fresh:
+                    self._page_ref[p] = 1
+                self._set_table_row(slot, pages)
+                n_rest = len(readopt)
+                if n_rest:
+                    # upload retired prefix-page content from the store onto
+                    # this slot's fresh pages BEFORE the prefill, so the
+                    # chunk loop attends it instead of re-forwarding it
+                    self._upload_pages(pages[n_adopt:n_adopt + n_rest],
+                                       readopt)
+                n_resident = n_adopt + n_rest
+                if self.prefix_sharing:
+                    # register this prompt's freshly written stable pages so
+                    # later sessions can adopt them (adopted ones already
+                    # are; re-adopted ones re-enter the map resident)
+                    for i, key in enumerate(plan["keys"]):
+                        if key not in self._prefix_map:
+                            self._register(key, pages[i])
+                    if n_resident:
+                        # tail-only admission write: resident pages hold the
+                        # identical (content-addressed) KV already — and CoW
+                        # says never write a page with refcount > 1
+                        host_mask = np.ones((self.layout.pages_per_slot,),
+                                            bool)
+                        host_mask[:n_resident] = False
+                        mask = jnp.asarray(host_mask)
         resident = n_resident * self.layout.page if self._paged else 0
         chunked = self.prefill_chunk is not None and \
             self.decode.supports_chunked_prefill(session.extras) and \
@@ -746,75 +779,83 @@ class SlotScheduler:
         extras_sig = tuple(sorted(
             (k, tuple(np.shape(v))) for k, v in (session.extras or {}).items()))
         t0 = time.perf_counter()
-        if admit_blob is not None:
-            # content-addressed admission-cache hit: the whole
-            # post-prefill slot state (+ its logits) restores in ONE
-            # jitted scatter — the O(N) resync/prefill never runs
-            bk_rows, kv_rows, _ = unflatten_slot_snapshot(admit_blob)
-            dev = {"bookkeeping": {n: jnp.asarray(np.asarray(v))
-                                   for n, v in bk_rows.items()},
-                   "kv": self._pad_kv_snapshot(kv_rows)}
-            self.state = self._restore(self.state, np.int32(slot), dev)
-            logits = jnp.asarray(np.asarray(admit_blob.arrays["logits"]))
-            fwd = 0
-            sig = ("admit_restore", extras_sig)
-            source = "store"
-            self.spill_stats["admit_store_hits"] += 1
-        elif chunked:
-            # KV-conditioned chunked admission: forward compute covers
-            # only the unshared tail (adopted pages are attended, not
-            # recomputed... except the one chunk the logits need), and
-            # every dispatch has a fixed shape — the compile signature
-            # is the BUCKET (chunk size x variants), not the prompt
-            # length, so K distinct lengths share one compiled set.
-            logits, self.state, info = self.decode.prefill_into_slot_chunked(
-                self.params, self.state, np.int32(slot), session.prompt,
-                extras=session.extras, page_write_mask=mask,
-                resident_len=resident, chunk=self.prefill_chunk)
-            fwd = info["forward_tokens"]
-            sig = ("chunked", self.prefill_chunk, resident > 0,
-                   mask is not None, extras_sig)
-            source = "cold"
-        else:
-            logits, self.state = self._prefill_slot(
-                self.params, self.state, np.int32(slot),
-                jnp.asarray(session.prompt), extras=session.extras,
-                page_write_mask=mask)
-            fwd = len(session.prompt)
-            # the one-shot prefill retraces on any shape change: prompt
-            # length, mask presence, AND extras shapes
-            sig = (len(session.prompt), mask is not None, extras_sig)
-            source = "cold"
-        logits = jax.block_until_ready(logits)
+        with span("admit.prefill"):
+            if admit_blob is not None:
+                # content-addressed admission-cache hit: the whole
+                # post-prefill slot state (+ its logits) restores in ONE
+                # jitted scatter — the O(N) resync/prefill never runs
+                bk_rows, kv_rows, _ = unflatten_slot_snapshot(admit_blob)
+                dev = {"bookkeeping": {n: jnp.asarray(np.asarray(v))
+                                       for n, v in bk_rows.items()},
+                       "kv": self._pad_kv_snapshot(kv_rows)}
+                self.state = self._restore(self.state, np.int32(slot), dev)
+                logits = jnp.asarray(np.asarray(admit_blob.arrays["logits"]))
+                fwd = 0
+                sig = ("admit_restore", extras_sig)
+                source = "store"
+                self.spill_stats["admit_store_hits"] += 1
+            elif chunked:
+                # KV-conditioned chunked admission: forward compute covers
+                # only the unshared tail (adopted pages are attended, not
+                # recomputed... except the one chunk the logits need), and
+                # every dispatch has a fixed shape — the compile signature
+                # is the BUCKET (chunk size x variants), not the prompt
+                # length, so K distinct lengths share one compiled set.
+                logits, self.state, info = \
+                    self.decode.prefill_into_slot_chunked(
+                        self.params, self.state, np.int32(slot),
+                        session.prompt, extras=session.extras,
+                        page_write_mask=mask, resident_len=resident,
+                        chunk=self.prefill_chunk)
+                fwd = info["forward_tokens"]
+                sig = ("chunked", self.prefill_chunk, resident > 0,
+                       mask is not None, extras_sig)
+                source = "cold"
+            else:
+                logits, self.state = self._prefill_slot(
+                    self.params, self.state, np.int32(slot),
+                    jnp.asarray(session.prompt), extras=session.extras,
+                    page_write_mask=mask)
+                fwd = len(session.prompt)
+                # the one-shot prefill retraces on any shape change: prompt
+                # length, mask presence, AND extras shapes
+                sig = (len(session.prompt), mask is not None, extras_sig)
+                source = "cold"
+            logits = jax.block_until_ready(logits)
         self._key_cache.pop(session.sid, None)
-        self.admit_stats.append(StepStats(
+        stat = StepStats(
             "admit", time.perf_counter() - t0, tokens=len(session.prompt),
             compiled=tag_compiled(self._warm, "admit", sig),
-            forward_tokens=fwd, source=source))
+            forward_tokens=fwd, source=source, start=t_plan,
+            sid=session.sid, queued_s=t_plan - session.submit_wall)
+        self.admit_stats.append(stat)
         if admit_blob is None and plan.get("admit_key") is not None:
             # cacheable cold admission: the just-admitted slot state is a
             # pure function of the prompt — snapshot it (pre-sampling)
             # with its logits so the NEXT admission of this prompt is an
             # O(1) restore.  Unpinned: LRU decides how long it lives.
-            snap = self._snapshot_slot_host(
-                slot, self._live_pages(session) if self._paged else None)
-            blob = flatten_slot_snapshot(snap, {"kind": "admit"})
-            blob.arrays["logits"] = np.asarray(logits)
-            self.store.put(plan["admit_key"], blob)
-            self.spill_stats["admit_store_puts"] += 1
+            with span("admit.store"):
+                snap = self._snapshot_slot_host(
+                    slot, self._live_pages(session) if self._paged else None)
+                blob = flatten_slot_snapshot(snap, {"kind": "admit"})
+                blob.arrays["logits"] = np.asarray(logits)
+                self.store.put(plan["admit_key"], blob)
+                self.spill_stats["admit_store_puts"] += 1
         # per-session sampling chain: seeded from the session (never
         # from slot position / batch composition), advanced once here
         # for the first token and once per live step on device after —
         # so the chain position is always the generated-token count and
         # the stream replays identically across runs and policies.
-        chain = jax.random.PRNGKey(session.seed) if session.seed is not None \
-            else jax.random.fold_in(self._base_key, session.sid)
-        pair = jax.random.split(chain)
-        t0k = sample_tokens(logits[None],
-                            jnp.full((1,), session.temperature),
-                            pair[1][None])[0]
-        self.slot_keys = self.slot_keys.at[slot].set(pair[0])
-        self.last_token = self.last_token.at[slot].set(t0k)
+        with span("admit.first_token"):
+            chain = jax.random.PRNGKey(session.seed) \
+                if session.seed is not None \
+                else jax.random.fold_in(self._base_key, session.sid)
+            pair = jax.random.split(chain)
+            t0k = sample_tokens(logits[None],
+                                jnp.full((1,), session.temperature),
+                                pair[1][None])[0]
+            self.slot_keys = self.slot_keys.at[slot].set(pair[0])
+            self.last_token = self.last_token.at[slot].set(t0k)
         session.slot = slot
         self.sessions[slot] = session
         self.active[slot] = True
@@ -829,8 +870,8 @@ class SlotScheduler:
             self.drafter.admit(slot, list(session.prompt) + session.tokens)
         if self.telemetry is not None:
             self.telemetry.on_tokens(session, len(session.tokens),
-                                     self.clock,
-                                     self.admit_stats[-1].compiled)
+                                     self.clock, stat.compiled)
+        stat.total_s = time.perf_counter() - t_plan
 
     def admit_pending(self) -> bool:
         """Admit as many pending sessions as free slots/pages allow.
@@ -851,23 +892,25 @@ class SlotScheduler:
         free = [i for i in range(self.slots) if not self.active[i]]
         admitted = False
         while free and self.pending:
-            head = self.pending[0]
-            if self._head_skips >= self.max_head_skips:
-                candidates: List[Session] = [head]   # budget spent
-            else:
-                candidates = self.policy.order_pending(
-                    list(self.pending), self)
-            chosen = None
-            plan = None
-            for cand in candidates:
-                p = self._admission_plan(cand)
-                if p is None:
-                    continue           # blocked on pool pages — try next
-                if cand is not head and \
-                        self.policy.defer_admission(self, cand, p):
-                    continue           # policy holds it back (never head)
-                chosen, plan = cand, p
-                break
+            t_plan = time.perf_counter()
+            with span("admit.plan"):
+                head = self.pending[0]
+                if self._head_skips >= self.max_head_skips:
+                    candidates: List[Session] = [head]   # budget spent
+                else:
+                    candidates = self.policy.order_pending(
+                        list(self.pending), self)
+                chosen = None
+                plan = None
+                for cand in candidates:
+                    p = self._admission_plan(cand)
+                    if p is None:
+                        continue           # blocked on pool pages — try next
+                    if cand is not head and \
+                            self.policy.defer_admission(self, cand, p):
+                        continue           # policy holds it back (never head)
+                    chosen, plan = cand, p
+                    break
             if chosen is None:
                 break                  # nothing admissible this round
             for i, s in enumerate(self.pending):
@@ -879,7 +922,8 @@ class SlotScheduler:
             else:
                 self._head_skips += 1
             slot = free.pop(0)
-            self._admit(chosen, slot, plan)
+            with span("admit", sid=chosen.sid):
+                self._admit(chosen, slot, plan, t_plan)
             admitted = True
             if chosen.done:
                 self._release(slot)
@@ -948,43 +992,45 @@ class SlotScheduler:
 
     # ------------------------------------------------------------------
     def _release(self, slot: int) -> None:
-        if self.drafter is not None:
-            self.drafter.release(slot)
-        self.sessions[slot] = None
-        self.active[slot] = False
-        self.temps[slot] = 0.0
-        self.eos[slot] = -1
-        if self._paged:
-            # retarget the table row at TRASH before the clearing write
-            # below, so clearing zeros can never land on a page another
-            # slot still references (prefix sharing); then drop refs —
-            # a page is recycled (and leaves the prefix map) only at 0
-            trash_row = jnp.full((self.layout.pages_per_slot,),
-                                 self.layout.trash, jnp.int32)
-            pt = self.state.bookkeeping[LT.PAGE_TABLE].at[slot].set(trash_row)
-            self.state = self.state.with_bookkeeping(**{LT.PAGE_TABLE: pt})
-            retiring = []
-            for p in self._slot_pages[slot]:
-                self._page_ref[p] -= 1
-                if self._page_ref[p] == 0:
-                    # tiering bugfix: a refcount-0 prefix page used to
-                    # leave the content map the moment it recycled —
-                    # with a store it retires INTO the tier instead
-                    # (gathered below, before anything can reuse it)
-                    key = self._page_key.get(p)
-                    if self.store is not None and key is not None:
-                        retiring.append((p, key))
-                    self._unregister(p)
-                    self.free_pages.append(p)
-            self._slot_pages[slot] = []
-            if retiring:
-                self._retire_pages(retiring)
-        # clear the slot so stale phase counters can't keep firing the
-        # on-device resync for an empty row (paged: the writes land on
-        # the trash page — the slot no longer owns real pages)
-        self.state = self._clear(self.state, np.int32(slot),
-                                 self._empty_row)
-        self.last_token = self.last_token.at[slot].set(0)
+        with span("release", sid=self.sessions[slot].sid):
+            if self.drafter is not None:
+                self.drafter.release(slot)
+            self.sessions[slot] = None
+            self.active[slot] = False
+            self.temps[slot] = 0.0
+            self.eos[slot] = -1
+            if self._paged:
+                # retarget the table row at TRASH before the clearing write
+                # below, so clearing zeros can never land on a page another
+                # slot still references (prefix sharing); then drop refs —
+                # a page is recycled (and leaves the prefix map) only at 0
+                trash_row = jnp.full((self.layout.pages_per_slot,),
+                                     self.layout.trash, jnp.int32)
+                pt = self.state.bookkeeping[LT.PAGE_TABLE].at[slot].set(
+                    trash_row)
+                self.state = self.state.with_bookkeeping(**{LT.PAGE_TABLE: pt})
+                retiring = []
+                for p in self._slot_pages[slot]:
+                    self._page_ref[p] -= 1
+                    if self._page_ref[p] == 0:
+                        # tiering bugfix: a refcount-0 prefix page used to
+                        # leave the content map the moment it recycled —
+                        # with a store it retires INTO the tier instead
+                        # (gathered below, before anything can reuse it)
+                        key = self._page_key.get(p)
+                        if self.store is not None and key is not None:
+                            retiring.append((p, key))
+                        self._unregister(p)
+                        self.free_pages.append(p)
+                self._slot_pages[slot] = []
+                if retiring:
+                    self._retire_pages(retiring)
+            # clear the slot so stale phase counters can't keep firing the
+            # on-device resync for an empty row (paged: the writes land on
+            # the trash page — the slot no longer owns real pages)
+            self.state = self._clear(self.state, np.int32(slot),
+                                     self._empty_row)
+            self.last_token = self.last_token.at[slot].set(0)
 
     # ------------------------------------------------------------------
     def _preempt_for_pending(self) -> int:
@@ -1025,6 +1071,10 @@ class SlotScheduler:
         — the deterministic time base for SLO deadlines and telemetry.
         Returns False when no progress was made — nothing admitted and
         nothing could decode."""
+        with span("step"):
+            return self._step()
+
+    def _step(self) -> bool:
         self.clock += 1
         admitted = self.admit_pending()
         if self.store is not None and self.preempt_chunks is not None \
@@ -1034,36 +1084,43 @@ class SlotScheduler:
         if not self.active.any():
             self._tick_telemetry()
             return admitted
-        run_mask = self._cow_before_chunk() if self.prefix_sharing \
-            else self.active
+        if self.prefix_sharing:
+            with span("cow"):
+                run_mask = self._cow_before_chunk()
+        else:
+            run_mask = self.active
         if not run_mask.any():
             self._tick_telemetry()
             return admitted            # every active slot fork-paused
         if self.speculate:
-            return self._spec_step(run_mask) or admitted
+            with span("spec"):
+                return self._spec_step(run_mask) or admitted
         t0 = time.perf_counter()
-        toks, self.state, self.slot_keys = self._chunk(
-            self.params, self.state, self.last_token, self.slot_keys,
-            jnp.asarray(self.temps), jnp.asarray(run_mask),
-            n_steps=self.chunk_size, eos=jnp.asarray(self.eos))
-        self.last_token = toks[:, -1]
-        host_toks = np.asarray(toks)         # the ONE host sync per chunk
+        with span("chunk.dispatch"):
+            toks, self.state, self.slot_keys = self._chunk(
+                self.params, self.state, self.last_token, self.slot_keys,
+                jnp.asarray(self.temps), jnp.asarray(run_mask),
+                n_steps=self.chunk_size, eos=jnp.asarray(self.eos))
+            self.last_token = toks[:, -1]
+        with span("chunk.sync"):
+            host_toks = np.asarray(toks)     # the ONE host sync per chunk
         compiled = tag_compiled(self._warm, "chunk")
         self.stats.append(StepStats(
             "chunk", time.perf_counter() - t0, tokens=self.chunk_size,
-            compiled=compiled))
-        for slot in np.nonzero(run_mask)[0]:
-            self._slot_chunks[slot] += 1
-            sess = self.sessions[slot]
-            before = len(sess.tokens)
-            sess.deliver(host_toks[slot])
-            if self.telemetry is not None:
-                self.telemetry.on_tokens(sess, len(sess.tokens) - before,
-                                         self.clock, compiled)
-            if sess.done:
-                self._release(slot)
+            compiled=compiled, start=t0))
+        with span("deliver"):
+            for slot in np.nonzero(run_mask)[0]:
+                self._slot_chunks[slot] += 1
+                sess = self.sessions[slot]
+                before = len(sess.tokens)
+                sess.deliver(host_toks[slot])
                 if self.telemetry is not None:
-                    self.telemetry.on_retire(sess, self.clock)
+                    self.telemetry.on_tokens(sess, len(sess.tokens) - before,
+                                             self.clock, compiled)
+                if sess.done:
+                    self._release(slot)
+                    if self.telemetry is not None:
+                        self.telemetry.on_retire(sess, self.clock)
         self._tick_telemetry()
         return True
 

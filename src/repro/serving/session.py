@@ -84,6 +84,11 @@ class Session:
     # submit-time scheduler clock (chunk units) — set by ``submit``; the
     # anchor for TTFT/queue-wait accounting and deadline slack.
     submit_clock: Optional[int] = None
+    # ``time.perf_counter()`` at submit, and at the start of the
+    # session's latest admission: the wall-clock queue wait, on the
+    # clock of the scheduler's spans and ``StepStats``.
+    submit_wall: Optional[float] = None
+    admit_wall: Optional[float] = None
     # saved per-slot PRNG key across a spill (the chain position is
     # ``len(tokens)``, so restoring this key resumes the exact stream).
     sample_chain: Optional[np.ndarray] = None

@@ -187,6 +187,26 @@ def test_telemetry_summary_shapes():
     assert s["pool_occupancy_mean"] == 0.5
 
 
+def test_telemetry_queue_wait_seconds_reads_the_session_stamps():
+    """Wall queue wait runs from the session's ``submit_wall`` to its first
+    ``admit_wall`` (the scheduler's ``StepStats`` clock); a resume does
+    not move it."""
+    tel = ServingTelemetry()
+    waits = [0.25, 0.5, 2.0]
+    for i, w in enumerate(waits):
+        s = _session()
+        s.submit_wall = 100.0 + i
+        tel.on_submit(s, clock=i)
+        s.admit_wall = s.submit_wall + w
+        tel.on_admit(s, clock=i + 1, source="cold")
+        s.admit_wall += 7.0                          # spilled, resumed later
+        tel.on_admit(s, clock=i + 9, source="resume")
+        assert tel.records[s.sid].queue_wait_seconds == pytest.approx(w)
+    q = tel.summary()["queue_wait_s"]
+    assert q["p50"] == pytest.approx(0.5) and q["p99"] == pytest.approx(2.0)
+    assert tel.summary()["queue_wait_chunks"]["p50"] == 1.0
+
+
 def test_session_record_single_token_stream_meets_itl():
     rec = SessionRecord(sid=0, slo_itl_chunks=1)
     rec.tokens_out = 1
