@@ -595,7 +595,8 @@ def resync(params: Params, cache: Dict[str, Any], cfg: ModelConfig,
 
 def decode_step_views(params: Params, cache: Dict[str, Any],
                       token: jax.Array, cfg: ModelConfig,
-                      mode: str = "tconst"
+                      mode: str = "tconst",
+                      live: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Layout-native cache-hit step (paper Eq. 5): strictly O(1) compute
     and memory reads for mode="tconst".  ``cache`` maps bookkeeping names
@@ -605,8 +606,10 @@ def decode_step_views(params: Params, cache: Dict[str, Any],
     loops) and the new token's K/V is appended *through* the views, so
     non-dense layouts never round-trip a dense logical cache.
 
-    token: (B,) int32.  Returns (logits (B, V), updated cache dict with
-    the same view/array structure).
+    token: (B,) int32.  ``live`` (B,) bool masks every write by row: a
+    frozen row's gen K/V, token id and ``gen_len`` come through
+    bit-identical (None: every row steps).  Returns (logits (B, V),
+    updated cache dict with the same view/array structure).
     """
     tc = cfg.tconst
     eps = cfg.norm_eps
@@ -630,7 +633,8 @@ def decode_step_views(params: Params, cache: Dict[str, Any],
             xn = rmsnorm(li["ln1"], x, eps)
             out, gki, gvi = A.decode_attend_view(
                 li["attn"], xn, gkb.layer(i), gvb.layer(i),
-                cache["gen_len"], cos_q, sin_q, cfg.logit_softcap)
+                cache["gen_len"], cos_q, sin_q, cfg.logit_softcap,
+                live=live)
             gkb = gkb.set_layer(i, gki)
             gvb = gvb.set_layer(i, gvi)
             if i >= 1:
@@ -660,8 +664,11 @@ def decode_step_views(params: Params, cache: Dict[str, Any],
     cache = dict(cache)
     cache["gen_k"], cache["gen_v"] = gk, gv
     # record the token id into the O(N) id buffer (int32, not KV cache)
-    cache["tokens"] = cache["tokens"].at[jnp.arange(B), pos].set(token)
-    cache["gen_len"] = cache["gen_len"] + 1
+    wpos = LT.frozen_index(pos, live, cache["tokens"].shape[1])
+    cache["tokens"] = cache["tokens"].at[jnp.arange(B), wpos].set(
+        token, mode="drop")
+    cache["gen_len"] = cache["gen_len"] + (
+        1 if live is None else live.astype(jnp.int32))
     return logits, cache
 
 

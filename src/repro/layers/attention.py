@@ -249,20 +249,23 @@ def decode_attend_view(params: Params, x: jax.Array, k_view, v_view,
                        cos_q: Optional[jax.Array] = None,
                        sin_q: Optional[jax.Array] = None,
                        logit_softcap: float = 0.0,
-                       window: "int | jax.Array" = 0):
+                       window: "int | jax.Array" = 0,
+                       live: Optional[jax.Array] = None):
     """Layout-native one-token decode (:func:`decode_attend` over
     KVViews): project q/k/v for the new token, append K/V *through the
     view* (paged: only the owning page is touched; int8: the vector is
     quantized in place), attend over slots ``<= cache_len`` in the
-    physical representation.  Returns (out (B,1,d), k_view, v_view)."""
+    physical representation.  ``live`` (B,) bool masks the append by
+    row (frozen rows write nothing; their output is garbage the caller
+    discards).  Returns (out (B,1,d), k_view, v_view)."""
     from repro.layers.rope import apply_rope
     dtype = x.dtype
     q, k_new, v_new = qkv_proj(params, x, x, dtype)
     if cos_q is not None:
         q = apply_rope(q, cos_q, sin_q)
         k_new = apply_rope(k_new, cos_q, sin_q)
-    k_view = k_view.write_token(cache_len, k_new[:, 0])
-    v_view = v_view.write_token(cache_len, v_new[:, 0])
+    k_view = k_view.write_token(cache_len, k_new[:, 0], live)
+    v_view = v_view.write_token(cache_len, v_new[:, 0], live)
     o = _attend_views(q, k_view, v_view, valid_len=cache_len + 1,
                       logit_softcap=logit_softcap, window=window)
     return out_proj(params, o, dtype), k_view, v_view

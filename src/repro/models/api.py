@@ -24,8 +24,10 @@ Two surfaces live here:
     ``init_state(slots, max_len)``          fixed-shape multi-slot state
     ``prefill_into_slot(params, state, slot, tokens)``
                                             admit one request mid-flight
-    ``step(params, state, token)``          one batched token, with the
-                                            W_og resync fused on-device
+    ``step(params, state, token, live)``    one batched token, with the
+                                            W_og resync fused on-device;
+                                            rows outside ``live`` frozen
+                                            at every write
     ``sync_mask(state)``                    per-slot (B,) boundary mask
     ``sync_rows(params, state, rows)``      COMPACTED row-wise resync:
                                             gather only the masked rows,
@@ -39,9 +41,10 @@ Two surfaces live here:
   zero pending rows means zero work), replacing PR-1's monolithic
   compute-all-rows-then-select cond.  :func:`decode_chunk` scans
   ``step`` so a k-token decode chunk runs as ONE dispatch with zero
-  per-token host syncs, freezing slots whose on-device ``done`` flag
-  was set by EOS.  Implementations exist for the TConst core, the dense
-  LM family, and the encoder-decoder.
+  per-token host syncs, freezing inactive slots and slots whose
+  on-device ``done`` flag was set by EOS at each write of the step.
+  Implementations exist for the TConst core, the dense LM family, and
+  the encoder-decoder.
 
 Every entry point takes/returns plain pytrees so the launchers can jit
 them with explicit shardings.  ``input_specs`` produces the
@@ -375,9 +378,10 @@ class DecodeState:
 
     def where_rows(self, rows: jax.Array, other: "DecodeState"
                    ) -> "DecodeState":
-        """Per-slot select: take self where ``rows`` (B,) is True, else
-        ``other``.  Used to freeze inactive/done slots inside a decode
-        chunk."""
+        """Per-slot select over the whole state: take self where ``rows``
+        (B,) is True, else ``other``.  Reads and writes every buffer, so
+        the decode chunk freezes rows at the step's writes instead
+        (``DecodeAPI.step``'s ``live``); the speculative round keeps it."""
         bk = {name: where_rows(rows, leaf, other.bookkeeping[name],
                                self.axes[name])
               for name, leaf in self.bookkeeping.items()}
@@ -464,11 +468,12 @@ def decode_chunk(decode: "DecodeAPI", params: Any, state: DecodeState,
 
     token: (B,) the token each slot feeds at the first step (its last
     sampled token).  active: (B,) bool; inactive slots are frozen
-    bit-identically and keep echoing their input token.  eos: optional
-    (B,) int32 end-of-sequence id per slot (< 0 disables); a slot that
-    samples its EOS sets the on-device ``done`` flag in
-    ``state.bookkeeping`` and is frozen for the rest of the chunk — the
-    scheduler evicts it at the chunk boundary.  Returns (sampled tokens
+    bit-identically and keep echoing their input token — frozen at the
+    step's writes (``live``), never by a select over the whole state.
+    eos: optional (B,) int32 end-of-sequence id per slot (< 0
+    disables); a slot that samples its EOS sets the on-device ``done``
+    flag in ``state.bookkeeping`` and is frozen for the rest of the
+    chunk — the scheduler evicts it at the chunk boundary.  Returns (sampled tokens
     (B, n_steps), state, key).
 
     key: a single PRNG key (engine path: one split per step, shared
@@ -480,31 +485,31 @@ def decode_chunk(decode: "DecodeAPI", params: Any, state: DecodeState,
     per_slot = key.ndim == 2
 
     # the named scopes are op metadata only: they label the profile's
-    # ops (step / sample / the freeze of non-live rows) and change no
-    # compiled program
+    # ops (step / sample / the per-row selects of the key, token and
+    # EOS flag) and change no compiled program
     def body(carry, _):
         state, tok, key = carry
         done = state.bookkeeping["done"]
         live = jnp.logical_and(active, jnp.logical_not(done))
         with jax.named_scope("decode.step"):
-            logits, new_state = decode.step(params, state, tok)
+            logits, state = decode.step(params, state, tok, live)
         with jax.named_scope("decode.sample"):
             if per_slot:
                 pair = jax.vmap(jax.random.split)(key)   # (B, 2, 2)
                 nxt_key, sub = pair[:, 0], pair[:, 1]
-                key = jnp.where(live[:, None], nxt_key, key)
             else:
                 key, sub = jax.random.split(key)
             nxt = sample_tokens(logits, temperature, sub)
-            nxt = jnp.where(live, nxt, tok)
         with jax.named_scope("decode.select"):
-            new_state = new_state.where_rows(live, state)
+            if per_slot:
+                key = jnp.where(live[:, None], nxt_key, key)
+            nxt = jnp.where(live, nxt, tok)
             if eos is not None:
                 hit = jnp.logical_and(
                     live, jnp.logical_and(eos >= 0, nxt == eos))
-                new_state = new_state.with_bookkeeping(
+                state = state.with_bookkeeping(
                     done=jnp.logical_or(done, hit))
-        return (new_state, nxt, key), nxt
+        return (state, nxt, key), nxt
 
     (state, _, key), toks = jax.lax.scan(
         body, (state, token, key), None, length=n_steps)
@@ -669,9 +674,12 @@ class DecodeAPI:
         writes only the unshared tail of the prompt."""
         raise NotImplementedError
 
-    def raw_step(self, params, state: DecodeState, token: jax.Array
+    def raw_step(self, params, state: DecodeState, token: jax.Array,
+                 live: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, DecodeState]:
-        """One cache-hit decode step, NO sync check (instrumentation)."""
+        """One cache-hit decode step, NO sync check (instrumentation).
+        ``live`` (B,) bool masks every write by row: frozen rows come
+        through bit-identical (None: every row steps)."""
         raise NotImplementedError
 
     # sync surface (identity for models without periodic resync) ------------
@@ -686,11 +694,16 @@ class DecodeAPI:
         come through bit-identical AND uncomputed."""
         return state
 
-    def maybe_sync(self, params, state: DecodeState) -> DecodeState:
-        """Derived fused sync: ``sync_rows`` over ``sync_mask``.  Zero
-        masked rows means zero sync work — this is the on-device
-        decision, no host round-trip."""
-        return self.sync_rows(params, state, self.sync_mask(state))
+    def maybe_sync(self, params, state: DecodeState,
+                   live: Optional[jax.Array] = None) -> DecodeState:
+        """Derived fused sync: ``sync_rows`` over ``sync_mask`` (and over
+        ``live`` rows only, when given).  Zero masked rows means zero
+        sync work — this is the on-device decision, no host
+        round-trip."""
+        rows = self.sync_mask(state)
+        if live is not None:
+            rows = jnp.logical_and(rows, live)
+        return self.sync_rows(params, state, rows)
 
     # chunked KV-conditioned prefill (admission path) ------------------------
     def supports_chunked_prefill(self, extras: Optional[Dict[str, Any]]
@@ -881,10 +894,14 @@ class DecodeAPI:
         return None
 
     # fused step ------------------------------------------------------------
-    def step(self, params, state: DecodeState, token: jax.Array
+    def step(self, params, state: DecodeState, token: jax.Array,
+             live: Optional[jax.Array] = None
              ) -> Tuple[jax.Array, DecodeState]:
-        """maybe_sync + raw_step: the unit scanned by decode_chunk."""
-        return self.raw_step(params, self.maybe_sync(params, state), token)
+        """maybe_sync + raw_step: the unit scanned by decode_chunk.  Rows
+        outside ``live`` (B,) are neither synced nor written: they come
+        through bit-identical (None: every row steps)."""
+        return self.raw_step(params, self.maybe_sync(params, state, live),
+                             token, live)
 
     # speculative decoding surface (see serving/speculative.py) -------------
     def supports_speculative(self) -> bool:
@@ -1137,11 +1154,11 @@ class TConstDecode(DecodeAPI):
         return np.logical_and(gen + n_steps >= self.cfg.tconst.w_og,
                               np.logical_not(done))
 
-    def raw_step(self, params, state, token):
+    def raw_step(self, params, state, token, live=None):
         with self._mesh_scope():
             logits, out = TC.decode_step_views(params, state.decode_views(),
                                                token, self.cfg,
-                                               mode=self.mode)
+                                               mode=self.mode, live=live)
         return logits, state.absorb(out)
 
     # speculative surface: verify writes into the O(1) gen window; a
@@ -1255,11 +1272,12 @@ class DenseDecode(DecodeAPI):
         return logits[0], state.with_slot(slot, self._row_state(cache),
                                           page_write_mask=page_write_mask)
 
-    def raw_step(self, params, state, token):
+    def raw_step(self, params, state, token, live=None):
         with self._mesh_scope():
             logits, out = LM.lm_decode_step_views(params,
                                                   state.decode_views(),
-                                                  token, self.cfg)
+                                                  token, self.cfg,
+                                                  live=live)
         return logits, state.absorb(out)
 
     def supports_speculative(self):
@@ -1339,11 +1357,12 @@ class EncDecDecode(DecodeAPI):
         return logits[0], state.with_slot(slot, self._row_state(cache),
                                           page_write_mask=page_write_mask)
 
-    def raw_step(self, params, state, token):
+    def raw_step(self, params, state, token, live=None):
         with self._mesh_scope():
             logits, out = ED.encdec_decode_step_views(params,
                                                       state.decode_views(),
-                                                      token, self.cfg)
+                                                      token, self.cfg,
+                                                      live=live)
         return logits, state.absorb(out)
 
     def supports_speculative(self):

@@ -304,13 +304,15 @@ def encdec_prefill_chunk(params: Params, row: Dict[str, Any],
 
 
 def encdec_decode_step_views(params: Params, cache: Dict[str, Any],
-                             token: jax.Array, cfg: ModelConfig
+                             token: jax.Array, cfg: ModelConfig,
+                             live: Optional[jax.Array] = None
                              ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Layout-native one-token decode: KV names in ``cache`` are
     ``repro.models.layouts`` FieldViews.  The growing decoder KV (paged /
     int8) is appended and attended in its physical representation; the
     fixed-size cross K/V is read through its view (int8-capable).
-    token: (B,) -> (logits (B, V), cache)."""
+    ``live`` (B,) bool masks the append and ``len`` by row (None: every
+    row steps).  token: (B,) -> (logits (B, V), cache)."""
     dtype = jnp.dtype(cfg.dtype)
     eps = cfg.norm_eps
     x = E.embed_tokens(params["embed"], token[:, None], dtype)
@@ -324,7 +326,7 @@ def encdec_decode_step_views(params: Params, cache: Dict[str, Any],
         xn = layernorm(layer["ln1"], x, eps)
         out, kv, vv = A.decode_attend_view(
             layer["attn"], xn, k_all.layer(i), v_all.layer(i),
-            cache["len"], cos, sin, 0.0, window)
+            cache["len"], cos, sin, 0.0, window, live)
         x = x + out
         xc = layernorm(layer["lnc"], x, eps)
         x = x + A.cross_attend_view(
@@ -339,7 +341,8 @@ def encdec_decode_step_views(params: Params, cache: Dict[str, Any],
     cache["k"], cache["v"] = k_all, v_all
     x = layernorm(params["dec_norm"], x, eps)
     logits = E.lm_head(params["embed"], x)[:, 0]
-    cache["len"] = cache["len"] + 1
+    cache["len"] = cache["len"] + (
+        1 if live is None else live.astype(jnp.int32))
     return logits, cache
 
 

@@ -167,9 +167,13 @@ class FieldView:
     def dense(self) -> jax.Array:
         raise NotImplementedError
 
-    def write_token(self, pos: jax.Array, vec: jax.Array) -> "FieldView":
+    def write_token(self, pos: jax.Array, vec: jax.Array,
+                    live: Optional[jax.Array] = None) -> "FieldView":
         """Append one (B, KV, D) vector at per-slot position ``pos`` (B,).
-        Only valid at the per-layer level."""
+        Only valid at the per-layer level.  ``live`` (B,) bool masks the
+        write by row: a frozen row writes nothing, so its buffers stay
+        bit-identical — the freeze contract of ``decode_chunk``, which
+        every layout's append must honour (None: every row writes)."""
         raise NotImplementedError
 
     def scatter_rows(self, idx: jax.Array, sel: jax.Array,
@@ -178,6 +182,15 @@ class FieldView:
         into slots ``idx`` (k,), but only where ``sel`` (k,) is True —
         unselected slots come through bit-identical.  Stacked level."""
         raise NotImplementedError
+
+
+def frozen_index(idx: jax.Array, live: Optional[jax.Array],
+                 size: int) -> jax.Array:
+    """Write index of a row-masked scatter: ``idx`` for live rows,
+    ``size`` (one past the end) for frozen ones, whose update the
+    scatter then drops (``mode="drop"``) — a frozen row's write costs
+    nothing and leaves every bit in place.  ``live=None``: all rows."""
+    return idx if live is None else jnp.where(live, idx, size)
 
 
 def _put_selected(arr: jax.Array, idx: jax.Array, sel: jax.Array,
@@ -214,10 +227,11 @@ class DenseView(FieldView):
     def dense(self):
         return self.data
 
-    def write_token(self, pos, vec):
+    def write_token(self, pos, vec, live=None):
         b = jnp.arange(vec.shape[0])
+        pos = frozen_index(pos, live, self.data.shape[1])
         return DenseView(self.data.at[b, pos].set(
-            vec.astype(self.data.dtype)), self.batch_axis)
+            vec.astype(self.data.dtype), mode="drop"), self.batch_axis)
 
     def scatter_rows(self, idx, sel, rows):
         return DenseView(_put_selected(self.data, idx, sel, rows,
@@ -256,11 +270,12 @@ class QuantView(FieldView):
     def dense(self):
         return dequantize_int8(self.q, self.scale, jnp.dtype(self.dtype))
 
-    def write_token(self, pos, vec):
+    def write_token(self, pos, vec, live=None):
         b = jnp.arange(vec.shape[0])
+        pos = frozen_index(pos, live, self.q.shape[1])
         qv, sv = quantize_int8(vec)
-        return QuantView(self.q.at[b, pos].set(qv),
-                         self.scale.at[b, pos].set(sv),
+        return QuantView(self.q.at[b, pos].set(qv, mode="drop"),
+                         self.scale.at[b, pos].set(sv, mode="drop"),
                          self.batch_axis, self.dtype)
 
     def scatter_rows(self, idx, sel, rows):
@@ -355,20 +370,22 @@ class PagedView(FieldView):
             x = jnp.pad(x, widths)
         return x.reshape(x.shape[:la] + (pps, self.page) + x.shape[la + 1:])
 
-    def write_token(self, pos, vec):
+    def write_token(self, pos, vec, live=None):
         """Append through the page table: physical page ``pt[b, pos //
-        page]``, offset ``pos % page`` — only the owning page is touched."""
+        page]``, offset ``pos % page`` — only the owning page is touched
+        (a frozen row's page index points past the pool: no page is)."""
         assert self.lead == 0, "write_token needs a per-layer view"
         b = jnp.arange(vec.shape[0])
-        pidx = self.page_table[b, pos // self.page]
+        pidx = frozen_index(self.page_table[b, pos // self.page], live,
+                            self.trash + 1)
         off = pos % self.page
         if self.quant:
             qv, sv = quantize_int8(vec)
             return self._rebuild((
-                self.storage.q.at[pidx, off].set(qv),
-                self.storage.scale.at[pidx, off].set(sv)))
+                self.storage.q.at[pidx, off].set(qv, mode="drop"),
+                self.storage.scale.at[pidx, off].set(sv, mode="drop")))
         return self._rebuild((self.storage.data.at[pidx, off].set(
-            vec.astype(self.storage.data.dtype)),))
+            vec.astype(self.storage.data.dtype), mode="drop"),))
 
     def scatter_rows(self, idx, sel, rows):
         """Write k dense logical rows through the rows' own pages (page-

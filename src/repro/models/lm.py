@@ -31,7 +31,8 @@ from repro.layers import attention as A
 from repro.layers import embed as E
 from repro.layers import rope as R
 from repro.layers import ssm as S
-from repro.layers.common import (Params, init_rmsnorm, rmsnorm, split_keys)
+from repro.layers.common import (Params, init_rmsnorm, rmsnorm, split_keys,
+                                 where_rows)
 from repro.layers.mlp import init_swiglu, swiglu
 from repro.layers.moe import init_moe, moe_ffn
 from repro.kernels.xla_flash import flash_attention
@@ -287,11 +288,15 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int
 
 def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
                   cache_len: jax.Array, window: jax.Array, cfg: ModelConfig,
-                  moe: bool, cos: jax.Array, sin: jax.Array
+                  moe: bool, cos: jax.Array, sin: jax.Array,
+                  live: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Per-layer decode over KVViews: ``cache_slice`` holds the per-layer
     ``repro.models.layouts`` FieldViews, so the attention walks the
-    physical representation (paged pool / int8) directly."""
+    physical representation (paged pool / int8) directly.  ``live``
+    (B,) bool freezes rows at the write: the K/V append is masked, and
+    the recurrent state, which a step rewrites whole, is selected per
+    row — O(1) a row."""
     from repro.models import layouts as LT
     eps = cfg.norm_eps
     new_slice: Dict[str, Any] = {}
@@ -300,11 +305,13 @@ def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
     def run_ssm():
         st = {"ssm": cache_slice["ssm"].dense(),
               "conv": cache_slice["conv"].dense()}
-        out, st = S.ssm_mixer(layer["ssm"], xn, cfg, state=st)
+        out, new = S.ssm_mixer(layer["ssm"], xn, cfg, state=st)
+        if live is not None:
+            new = {k: where_rows(live, new[k], st[k], 0) for k in new}
         # the recurrent state is never quantized/paged (mutated every
         # step), so a fresh DenseView is the identity re-wrap
-        return out, {"ssm": LT.DenseView(st["ssm"]),
-                     "conv": LT.DenseView(st["conv"])}
+        return out, {"ssm": LT.DenseView(new["ssm"]),
+                     "conv": LT.DenseView(new["conv"])}
 
     if cfg.arch_type == "ssm":
         out, st = run_ssm()
@@ -312,7 +319,7 @@ def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
         return x + out, new_slice
     out, k_view, v_view = A.decode_attend_view(
         layer["attn"], xn, cache_slice["k"], cache_slice["v"], cache_len,
-        cos, sin, cfg.logit_softcap, window)
+        cos, sin, cfg.logit_softcap, window, live)
     new_slice["k"], new_slice["v"] = k_view, v_view
     if cfg.hybrid_parallel:
         ssm_out, st = run_ssm()
@@ -325,12 +332,15 @@ def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
 
 def lm_decode_step_views(params: Params, cache: Dict[str, Any],
                          token: jax.Array, cfg: ModelConfig,
-                         positions3: Optional[jax.Array] = None
+                         positions3: Optional[jax.Array] = None,
+                         live: Optional[jax.Array] = None
                          ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Layout-native one-token decode.  ``cache`` maps bookkeeping names
     to plain arrays and KV names to FieldViews; under the paged layout a
     step appends through the page table and attends page-by-page —
     nothing materialises the dense (layers, B, max_len, KV, D) view.
+    ``live`` (B,) bool masks every write by row: a frozen row's state
+    comes through bit-identical (None: every row steps).
     token: (B,) -> (logits (B, V), cache)."""
     B = token.shape[0]
     dtype = jnp.dtype(cfg.dtype)
@@ -344,7 +354,7 @@ def lm_decode_step_views(params: Params, cache: Dict[str, Any],
     for i, layer in enumerate(params.get("dense_layers", [])):
         sl = {"k": cache["dense_k"].layer(i), "v": cache["dense_v"].layer(i)}
         x, new = _layer_decode(layer, x, sl, cache["len"], windows[i], cfg,
-                               False, cos, sin)
+                               False, cos, sin, live)
         cache["dense_k"] = cache["dense_k"].set_layer(i, new["k"])
         cache["dense_v"] = cache["dense_v"].set_layer(i, new["v"])
 
@@ -366,7 +376,8 @@ def lm_decode_step_views(params: Params, cache: Dict[str, Any],
         layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         slc = {k: bufs[k].layer(i) for k in keys}
         x, new = _layer_decode(layer, x, slc, cache["len"],
-                               scan_windows[i], cfg, cfg.is_moe, cos, sin)
+                               scan_windows[i], cfg, cfg.is_moe, cos, sin,
+                               live)
         bufs = {k: bufs[k].set_layer(i, new[k]) for k in keys}
         return (x, bufs)
 
@@ -377,7 +388,8 @@ def lm_decode_step_views(params: Params, cache: Dict[str, Any],
         cache[k] = bufs[k]
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = E.lm_head(params["embed"], x, cfg.logit_softcap)[:, 0]
-    cache["len"] = cache["len"] + 1
+    cache["len"] = cache["len"] + (
+        1 if live is None else live.astype(jnp.int32))
     return logits, cache
 
 

@@ -80,6 +80,11 @@ class StepStats:
     sid: Optional[int] = None
     queued_s: Optional[float] = None
     total_s: Optional[float] = None
+    # "chunk" entries (scheduler): slots masked out of the dispatch —
+    # empty or fork-paused — and so frozen for the whole chunk.  Known
+    # on the host, no device sync; rows that hit EOS inside the chunk
+    # freeze on the device and are not counted.
+    frozen: Optional[int] = None
 
 
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:

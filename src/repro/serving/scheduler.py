@@ -1107,7 +1107,8 @@ class SlotScheduler:
         compiled = tag_compiled(self._warm, "chunk")
         self.stats.append(StepStats(
             "chunk", time.perf_counter() - t0, tokens=self.chunk_size,
-            compiled=compiled, start=t0))
+            compiled=compiled, start=t0,
+            frozen=self.slots - int(run_mask.sum())))
         with span("deliver"):
             for slot in np.nonzero(run_mask)[0]:
                 self._slot_chunks[slot] += 1

@@ -178,8 +178,8 @@ class TConstModelDrafter(Drafter):
 
         def body(c, state):
             live = jnp.logical_and(active, c < n_valid)
-            _, new_state = self.decode.step(params, state, toks[:, c])
-            return new_state.where_rows(live, state)
+            _, state = self.decode.step(params, state, toks[:, c], live)
+            return state
 
         return jax.lax.fori_loop(0, toks.shape[1], body, state)
 
