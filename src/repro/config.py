@@ -55,6 +55,8 @@ class TConstConfig:
 
 ARCH_TYPES = ("dense", "moe", "ssm", "hybrid", "audio", "vlm")
 ATTENTION_MODES = ("full", "sliding", "tconst", "tlin")
+LAYER_KINDS = ("mamba", "attention")
+POSITION_EMBEDDINGS = ("rope", "nope")
 
 
 @dataclass(frozen=True)
@@ -81,6 +83,13 @@ class ModelConfig:
     mrope: bool = False                  # qwen2-vl multimodal rope sections
     mrope_sections: Tuple[int, ...] = ()
     logit_softcap: float = 0.0
+    position_embedding: str = "rope"     # rope | nope (no positions)
+    attention_multiplier: float = 0.0    # score scale; 0 -> head_dim**-0.5
+
+    # residual path and head scalars (granite); 1.0 leaves the path as is
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0     # scales every branch before its add
+    logits_scaling: float = 1.0          # divides the logits
 
     # normalisation / activation ----------------------------------------------
     norm_eps: float = 1e-5
@@ -104,6 +113,10 @@ class ModelConfig:
 
     # hybrid (hymba): parallel attention + mamba heads in one layer
     hybrid_parallel: bool = False
+    # interleaved hybrid (granite-4.0-h): the kind of every layer, in
+    # order ("mamba" | "attention"); empty = one kind throughout, as
+    # arch_type says.  A list is accepted and stored as a tuple.
+    layer_pattern: Tuple[str, ...] = ()
 
     # encoder-decoder (whisper) ---------------------------------------------------
     encoder_layers: int = 0
@@ -121,6 +134,11 @@ class ModelConfig:
     dtype: str = "bfloat16"              # activation dtype
     param_dtype: str = "float32"
 
+    def __post_init__(self):
+        # the config is a static, hashed jit argument: a list (a JSON
+        # file's layer pattern) must become a tuple
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+
     # ------------------------------------------------------------------ helpers
     @property
     def resolved_head_dim(self) -> int:
@@ -137,6 +155,33 @@ class ModelConfig:
     @property
     def is_attention_free(self) -> bool:
         return self.arch_type == "ssm"
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """Attention score scale, or None for the kernels' default
+        ``head_dim ** -0.5``."""
+        return self.attention_multiplier or None
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of every layer in order: ``layer_pattern``, else one
+        kind throughout ("mamba" for an ssm arch, else "attention"; a
+        hymba attention layer carries its parallel mamba heads)."""
+        if self.layer_pattern:
+            return self.layer_pattern
+        kind = "mamba" if self.arch_type == "ssm" else "attention"
+        return (kind,) * self.n_layers
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Some layer carries Mamba-2 ssm/conv state: it cannot be rolled
+        back by a length decrement nor rebuilt from adopted K/V pages."""
+        return self.hybrid_parallel or "mamba" in self.layer_kinds
+
+    @property
+    def has_growing_kv(self) -> bool:
+        """Some layer appends K/V to a cache that grows with the stream."""
+        return "attention" in self.layer_kinds
 
     @property
     def is_encdec(self) -> bool:
@@ -160,6 +205,16 @@ class ModelConfig:
                 f"n_kv_heads {self.n_kv_heads}")
         if self.is_moe:
             assert 0 < self.n_experts_per_tok <= self.n_experts
+        assert self.position_embedding in POSITION_EMBEDDINGS, \
+            self.position_embedding
+        if self.layer_pattern:
+            assert len(self.layer_pattern) == self.n_layers, (
+                f"{self.name}: {len(self.layer_pattern)} layer kinds for "
+                f"{self.n_layers} layers")
+            assert set(self.layer_pattern) <= set(LAYER_KINDS), \
+                self.layer_pattern
+            assert not (self.is_moe or self.hybrid_parallel), \
+                f"{self.name}: a layer pattern takes dense layers only"
         if self.attention_mode == "tconst":
             assert self.n_layers % self.tconst.block_depth == 0 or \
                 self.n_layers >= self.tconst.block_depth, (
@@ -205,7 +260,7 @@ def register_arch(name: str) -> Callable:
 _ARCH_MODULES = [
     "mixtral_8x22b", "llama3_405b", "mamba2_130m", "deepseek_moe_16b",
     "smollm_360m", "minicpm_2b", "hymba_1_5b", "whisper_small",
-    "gemma3_4b", "qwen2_vl_2b", "tconst_41m",
+    "gemma3_4b", "qwen2_vl_2b", "tconst_41m", "granite_4_0_h_micro",
 ]
 
 
@@ -261,7 +316,7 @@ def reduced(cfg: ModelConfig, **overrides: Any) -> ModelConfig:
                   moe_d_ff=64, first_dense_layers=min(1, cfg.first_dense_layers),
                   n_shared_experts=min(1, cfg.n_shared_experts))
     if cfg.ssm_state:
-        kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=8)
+        kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=8, ssm_heads=0)
     if cfg.is_encdec:
         kw.update(encoder_layers=2, encoder_seq=16)
     if cfg.frontend != "none":
@@ -270,6 +325,10 @@ def reduced(cfg: ModelConfig, **overrides: Any) -> ModelConfig:
         kw["sliding_window"] = 8
     if cfg.mrope:
         kw["mrope_sections"] = (8, 4, 4)   # sums to head_dim//2 = 16
+    if cfg.layer_pattern:
+        # one layer of each kind, in the order they first appear
+        kinds = tuple(dict.fromkeys(cfg.layer_pattern))
+        kw.update(n_layers=len(kinds), layer_pattern=kinds)
     kw.update(overrides)
     out = cfg.replace(**kw)
     out.validate()

@@ -40,7 +40,7 @@ NEG_INF = -2.3819763e38
 
 
 def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, *rest, softcap: float,
-                   window: int, quant: bool):
+                   window: int, quant: bool, scale: Optional[float]):
     if quant:
         ks_ref, vs_ref, o_ref = rest
     else:
@@ -56,8 +56,8 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, *rest, softcap: float,
             k = k * ks_ref[0, :, h:h + 1]              # (S, 1) scales
             v = v * vs_ref[0, :, h:h + 1]
 
-        scale = q.shape[-1] ** -0.5
-        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+        sc = q.shape[-1] ** -0.5 if scale is None else scale
+        s = jax.lax.dot_general(q * sc, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, S)
         if softcap > 0.0:
             s = jnp.tanh(s / softcap) * softcap
@@ -80,10 +80,12 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             window: int = 0,
                             k_scale: Optional[jax.Array] = None,
                             v_scale: Optional[jax.Array] = None,
+                            scale: Optional[float] = None,
                             interpret: bool = False) -> jax.Array:
     """q: (B, H, D) one token per sequence; k/v: (B, S, KV, D);
     valid_len: (B,) — slots [0, valid_len) attended (``window`` > 0
     additionally limits attention to the last ``window`` of them).
+    ``scale`` multiplies the scores (None: ``D ** -0.5``).
     int8 KV: pass ``k_scale``/``v_scale`` (B, S, KV, 1) float32 and int8
     ``k``/``v`` — dequant is fused in-kernel.  Returns (B, H, D)."""
     B, H, D = q.shape
@@ -94,7 +96,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     quant = k_scale is not None
 
     kernel = functools.partial(_decode_kernel, softcap=softcap,
-                               window=window, quant=quant)
+                               window=window, quant=quant, scale=scale)
     # index maps receive (b, valid_len_ref): valid_len rides in SMEM
     in_specs = [
         pl.BlockSpec((1, KV, G, D), lambda b, vl: (b, 0, 0, 0)),   # q

@@ -35,7 +35,7 @@ INVALID_POS = jnp.iinfo(jnp.int32).max // 2
 
 def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, causal: bool, window: int,
-                  softcap: float, nk: int):
+                  softcap: float, nk: int, scale: Optional[float]):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -50,8 +50,8 @@ def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
     qp = qp_ref[0]                                     # (qb, 1)
     kp = kp_ref[0]                                     # (1, kb)
 
-    scale = q.shape[-1] ** -0.5
-    s = jax.lax.dot_general(q * scale, k,
+    sc = q.shape[-1] ** -0.5 if scale is None else scale
+    s = jax.lax.dot_general(q * sc, k,
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (qb, kb)
     if softcap > 0.0:
@@ -89,8 +89,10 @@ def flash_attention_fwd_pallas(
         q_pos: jax.Array, k_pos: jax.Array, *,
         causal: bool = True, window: int = 0, softcap: float = 0.0,
         block_q: int = 256, block_k: int = 512,
+        scale: Optional[float] = None,
         interpret: bool = False) -> jax.Array:
-    """q: (B, Lq, H, D); k/v: (B, Lk, KV, D); positions (B, Lq)/(B, Lk).
+    """q: (B, Lq, H, D); k/v: (B, Lk, KV, D); positions (B, Lq)/(B, Lk);
+    ``scale`` multiplies the scores (None: ``D ** -0.5``).
 
     Static ``window`` (the Pallas kernel specialises per layer type; the
     dynamic-window path is served by ``xla_flash``).  Returns (B, Lq, H, D).
@@ -116,7 +118,7 @@ def flash_attention_fwd_pallas(
     kpf = jnp.repeat(k_pos, H, axis=0)[:, None, :]
 
     kernel = functools.partial(_flash_kernel, causal=causal, window=window,
-                               softcap=softcap, nk=nk)
+                               softcap=softcap, nk=nk, scale=scale)
     out = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),

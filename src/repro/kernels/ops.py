@@ -110,24 +110,27 @@ def _shard_mapped(inner, mesh: Mesh, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_pallas_diff(q, k, v, q_pos, k_pos, causal, window, softcap):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_pallas_diff(q, k, v, q_pos, k_pos, causal, window, softcap,
+                       scale):
     return flash_attention_fwd_pallas(
         q, k, v, q_pos, k_pos, causal=causal, window=window,
-        softcap=softcap, interpret=_interpret())
+        softcap=softcap, scale=scale, interpret=_interpret())
 
 
-def _fp_fwd(q, k, v, q_pos, k_pos, causal, window, softcap):
-    o = _flash_pallas_diff(q, k, v, q_pos, k_pos, causal, window, softcap)
+def _fp_fwd(q, k, v, q_pos, k_pos, causal, window, softcap, scale):
+    o = _flash_pallas_diff(q, k, v, q_pos, k_pos, causal, window, softcap,
+                           scale)
     return o, (q, k, v, q_pos, k_pos)
 
 
-def _fp_bwd(causal, window, softcap, res, do):
+def _fp_bwd(causal, window, softcap, scale, res, do):
     q, k, v, q_pos, k_pos = res
     # backward via the (differentiable) XLA blocked implementation
     _, vjp = jax.vjp(
         lambda q_, k_, v_: XF.flash_attention(
-            q_, k_, v_, q_pos, k_pos, window, causal, softcap, 256, 512),
+            q_, k_, v_, q_pos, k_pos, window, causal, softcap, 256, 512,
+            scale),
         q, k, v)
     dq, dk, dv = vjp(do)
     return dq, dk, dv, None, None
@@ -139,15 +142,17 @@ _flash_pallas_diff.defvjp(_fp_fwd, _fp_bwd)
 def flash(q: jax.Array, k: jax.Array, v: jax.Array,
           q_pos: jax.Array, k_pos: jax.Array,
           window: "int | jax.Array" = 0, causal: bool = True,
-          softcap: float = 0.0) -> jax.Array:
-    """Dispatching flash attention (see module docstring)."""
+          softcap: float = 0.0, scale: Optional[float] = None
+          ) -> jax.Array:
+    """Dispatching flash attention (see module docstring); ``scale``
+    multiplies the scores (None: ``D ** -0.5``)."""
     static_window = isinstance(window, int)
     if _pallas_enabled() and static_window and \
             q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0:
         return _flash_pallas_diff(q, k, v, q_pos, k_pos, causal, window,
-                                  softcap)
+                                  softcap, scale)
     return XF.flash_attention(q, k, v, q_pos, k_pos, window, causal,
-                              softcap, 512, 512)
+                              softcap, 512, 512, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -159,19 +164,22 @@ def flash(q: jax.Array, k: jax.Array, v: jax.Array,
 PREFILL_CHUNK_FLASH_ELEMS = 1 << 22
 
 
-def _prefill_chunk_attention_impl(q, k, v, q_pos, k_pos, window, softcap):
+def _prefill_chunk_attention_impl(q, k, v, q_pos, k_pos, window, softcap,
+                                  scale=None):
     if q.shape[1] * k.shape[1] >= PREFILL_CHUNK_FLASH_ELEMS:
-        return flash(q, k, v, q_pos, k_pos, window, True, softcap)
+        return flash(q, k, v, q_pos, k_pos, window, True, softcap, scale)
     from repro.layers.attention import make_mask, sdpa
     mask = make_mask(q_pos, k_pos, "sliding", window)
-    return sdpa(q, k, v, mask, softcap)
+    return sdpa(q, k, v, mask, softcap, scale=scale)
 
 
 def prefill_chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             q_pos: jax.Array, k_pos: jax.Array,
                             window: "int | jax.Array" = 0,
-                            softcap: float = 0.0) -> jax.Array:
-    """One prefill chunk's C queries against the slot's row cache.
+                            softcap: float = 0.0,
+                            scale: Optional[float] = None) -> jax.Array:
+    """One prefill chunk's C queries against the slot's row cache
+    (``scale``: the score scale, None for ``D ** -0.5``).
 
     q: (B, C, H, D); k/v: (B, S, KV, D) — the row cache with positions
     [0, start + C) written (resident prefix + earlier chunks + this
@@ -207,12 +215,12 @@ def prefill_chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 w = window if static_window else o["window"]
                 return _prefill_chunk_attention_impl(
                     o["q"], o["k"], o["v"], o["q_pos"], o["k_pos"], w,
-                    softcap)
+                    softcap, scale)
 
             return _shard_mapped(inner, mesh, (specs,),
                                  P(db, None, mb, None))(operands)
     return _prefill_chunk_attention_impl(q, k, v, q_pos, k_pos, window,
-                                         softcap)
+                                         softcap, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -248,16 +256,18 @@ def decode_attend_kv(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _int8_decode_fused_impl(q, kq, vq, k_scale, v_scale, valid_len,
-                            softcap, window):
+                            softcap, window, scale=None):
     return decode_attention_pallas(
         q, kq, vq, valid_len, softcap=softcap, window=window,
-        k_scale=k_scale, v_scale=v_scale, interpret=_interpret())
+        k_scale=k_scale, v_scale=v_scale, scale=scale,
+        interpret=_interpret())
 
 
 def int8_decode_fused(q: jax.Array, kq: jax.Array, vq: jax.Array,
                       k_scale: jax.Array, v_scale: jax.Array,
                       valid_len: jax.Array, softcap: float = 0.0,
-                      window: int = 0) -> jax.Array:
+                      window: int = 0, scale: Optional[float] = None
+                      ) -> jax.Array:
     """Fused int8 decode: dequant happens inside the QK/AV loops (1 HBM
     byte per element).  Caller checks :func:`int8_fused_available`.
     Under a decode-mesh scope the int8 pools shard like their parents
@@ -268,7 +278,8 @@ def int8_decode_fused(q: jax.Array, kq: jax.Array, vq: jax.Array,
                             heads=(q.shape[1], kq.shape[2]))
         if db is not None or mb is not None:
             inner = functools.partial(_int8_decode_fused_impl,
-                                      softcap=softcap, window=window)
+                                      softcap=softcap, window=window,
+                                      scale=scale)
             kv_spec = P(db, None, mb, None)
             return _shard_mapped(
                 inner, mesh,
@@ -276,7 +287,7 @@ def int8_decode_fused(q: jax.Array, kq: jax.Array, vq: jax.Array,
                  P(db)),
                 P(db, mb, None))(q, kq, vq, k_scale, v_scale, valid_len)
     return _int8_decode_fused_impl(q, kq, vq, k_scale, v_scale, valid_len,
-                                   softcap, window)
+                                   softcap, window, scale)
 
 
 def int8_fused_available(window) -> bool:
@@ -292,21 +303,22 @@ def int8_fused_available(window) -> bool:
 
 
 def _paged_decode_impl(q, pool_k, pool_v, page_table, valid_len, *,
-                       softcap, window, k_scale, v_scale):
+                       softcap, window, k_scale, v_scale, scale=None):
     if _pallas_enabled():
         return paged_decode_attention_pallas(
             q, pool_k, pool_v, page_table, valid_len, softcap=softcap,
-            window=window, k_scale=k_scale, v_scale=v_scale,
+            window=window, k_scale=k_scale, v_scale=v_scale, scale=scale,
             interpret=_interpret())
     return paged_decode_attention_xla(
         q, pool_k, pool_v, page_table, valid_len, softcap=softcap,
-        window=window, k_scale=k_scale, v_scale=v_scale)
+        window=window, k_scale=k_scale, v_scale=v_scale, scale=scale)
 
 
 def paged_decode(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                  page_table: jax.Array, valid_len: jax.Array, *,
                  softcap: float = 0.0, window: "int | jax.Array" = 0,
-                 k_scale=None, v_scale=None) -> jax.Array:
+                 k_scale=None, v_scale=None,
+                 scale: Optional[float] = None) -> jax.Array:
     """Layout-native paged decode attention: Pallas page-table-walk
     kernel on the Pallas path (compiled on TPU, interpret elsewhere),
     page-at-a-time XLA scan otherwise.  Neither materialises the dense
@@ -344,13 +356,14 @@ def paged_decode(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     o["q"], o["pool_k"], o["pool_v"], o["page_table"],
                     o["valid_len"], softcap=softcap,
                     window=window if static_window else o["window"],
-                    k_scale=o.get("k_scale"), v_scale=o.get("v_scale"))
+                    k_scale=o.get("k_scale"), v_scale=o.get("v_scale"),
+                    scale=scale)
 
             return _shard_mapped(inner, mesh, (specs,),
                                  P(db, mb, None))(operands)
     return _paged_decode_impl(q, pool_k, pool_v, page_table, valid_len,
                               softcap=softcap, window=window,
-                              k_scale=k_scale, v_scale=v_scale)
+                              k_scale=k_scale, v_scale=v_scale, scale=scale)
 
 
 # ---------------------------------------------------------------------------

@@ -49,7 +49,8 @@ NEG_INF = -2.3819763e38
 
 
 def _paged_kernel(pt_ref, vl_ref, win_ref, q_ref, k_ref, v_ref, *rest,
-                  page: int, softcap: float, quant: bool):
+                  page: int, softcap: float, quant: bool,
+                  scale: Optional[float]):
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -76,8 +77,8 @@ def _paged_kernel(pt_ref, vl_ref, win_ref, q_ref, k_ref, v_ref, *rest,
             k = k * ks_ref[0, :, h:h + 1]               # (page, 1) scales
             v = v * vs_ref[0, :, h:h + 1]
 
-        scale = q.shape[-1] ** -0.5
-        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+        sc = q.shape[-1] ** -0.5 if scale is None else scale
+        s = jax.lax.dot_general(q * sc, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, page)
         if softcap > 0.0:
             s = jnp.tanh(s / softcap) * softcap
@@ -108,12 +109,14 @@ def paged_decode_attention_pallas(
         softcap: float = 0.0, window: "int | jax.Array" = 0,
         k_scale: Optional[jax.Array] = None,
         v_scale: Optional[jax.Array] = None,
+        scale: Optional[float] = None,
         interpret: bool = False) -> jax.Array:
     """q: (B, H, D) one token per slot; pool_k/pool_v: (pool+1, page, KV, D)
     shared page pools (last page = trash, masked off by ``valid_len``);
     page_table: (B, pages_per_slot) int32; valid_len: (B,) — logical slots
     [0, valid_len) attended.  int8 pools: pass (pool+1, page, KV, 1) f32
-    ``k_scale``/``v_scale`` (dequant fused in-kernel).  Returns (B, H, D)."""
+    ``k_scale``/``v_scale`` (dequant fused in-kernel).  ``scale``
+    multiplies the scores (None: ``D ** -0.5``).  Returns (B, H, D)."""
     B, H, D = q.shape
     n_pages, page, KV = pool_k.shape[0], pool_k.shape[1], pool_k.shape[2]
     pps = page_table.shape[1]
@@ -124,7 +127,7 @@ def paged_decode_attention_pallas(
     quant = k_scale is not None
 
     kernel = functools.partial(_paged_kernel, page=page, softcap=softcap,
-                               quant=quant)
+                               quant=quant, scale=scale)
     # index maps receive (b, j, *scalar_prefetch_refs): the page-table
     # ref picks the physical page for grid step (b, j) — the "in-kernel
     # page-table walk".
@@ -181,7 +184,8 @@ def paged_decode_attention_xla(
         page_table: jax.Array, valid_len: jax.Array, *,
         softcap: float = 0.0, window: "int | jax.Array" = 0,
         k_scale: Optional[jax.Array] = None,
-        v_scale: Optional[jax.Array] = None) -> jax.Array:
+        v_scale: Optional[jax.Array] = None,
+        scale: Optional[float] = None) -> jax.Array:
     """Same contract as the Pallas kernel, in plain XLA: a page-at-a-time
     ``lax.scan`` whose largest intermediate is one (B, page, KV, D) gather
     — never the dense (B, max_len, KV, D) logical view.  Two passes with
@@ -191,7 +195,8 @@ def paged_decode_attention_xla(
     page, KV = pool_k.shape[1], pool_k.shape[2]
     pps = page_table.shape[1]
     G = H // KV
-    qg = q.reshape(B, KV, G, D).astype(jnp.float32) * (D ** -0.5)
+    qg = q.reshape(B, KV, G, D).astype(jnp.float32) * (
+        D ** -0.5 if scale is None else scale)
     vl = valid_len.astype(jnp.int32)
     win = jnp.asarray(window, jnp.int32)
     weff = jnp.where(win > 0, win, jnp.int32(2 ** 30))

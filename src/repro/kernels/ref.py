@@ -18,13 +18,15 @@ INVALID_POS = jnp.iinfo(jnp.int32).max // 2
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   q_pos: jax.Array, k_pos: jax.Array,
                   window: int = 0, causal: bool = True,
-                  softcap: float = 0.0) -> jax.Array:
+                  softcap: float = 0.0,
+                  scale: Optional[float] = None) -> jax.Array:
     """Naive GQA attention.  q (B, Lq, H, D); k/v (B, Lk, KV, D);
-    q_pos (B, Lq); k_pos (B, Lk) with INVALID_POS marking dead slots."""
+    q_pos (B, Lq); k_pos (B, Lk) with INVALID_POS marking dead slots;
+    ``scale`` multiplies the scores (None: ``D ** -0.5``)."""
     B, Lq, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     qg = q.reshape(B, Lq, KV, G, D).astype(jnp.float32)
     s = jnp.einsum("blkgd,bskd->bklgs", qg * scale, k.astype(jnp.float32))
     if softcap > 0.0:
@@ -44,14 +46,15 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def decode_reference(q: jax.Array, k: jax.Array, v: jax.Array,
-                     valid_len: jax.Array, softcap: float = 0.0
-                     ) -> jax.Array:
+                     valid_len: jax.Array, softcap: float = 0.0,
+                     scale: Optional[float] = None) -> jax.Array:
     """Single-token decode oracle.  q (B, H, D); k/v (B, S, KV, D);
-    valid_len (B,): slots [0, valid_len) are attended."""
+    valid_len (B,): slots [0, valid_len) are attended; ``scale``
+    multiplies the scores (None: ``D ** -0.5``)."""
     B, H, D = q.shape
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     qg = q.reshape(B, KV, G, D).astype(jnp.float32)
     s = jnp.einsum("bkgd,bskd->bkgs", qg * scale, k.astype(jnp.float32))
     if softcap > 0.0:

@@ -68,10 +68,10 @@ def _block_mask(qpi, kpj, causal, window):
                                                      window)
 
 
-def _fwd(q, k, v, qp, kp, causal, window, softcap, qb, kb):
+def _fwd(q, k, v, qp, kp, causal, window, softcap, qb, kb, scale):
     N, Lq, D = q.shape
     Lk = k.shape[1]
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     nq, nk = Lq // qb, Lk // kb
     f32 = jnp.float32
     shared = qp.ndim == 1
@@ -122,10 +122,11 @@ def _fwd(q, k, v, qp, kp, causal, window, softcap, qb, kb):
     return o.astype(q.dtype), lse
 
 
-def _bwd(q, k, v, qp, kp, o, lse, do, causal, window, softcap, qb, kb):
+def _bwd(q, k, v, qp, kp, o, lse, do, causal, window, softcap, qb, kb,
+         scale):
     N, Lq, D = q.shape
     Lk = k.shape[1]
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     nq, nk = Lq // qb, Lk // kb
     f32 = jnp.float32
     shared = qp.ndim == 1
@@ -194,22 +195,24 @@ def _bwd(q, k, v, qp, kp, o, lse, do, causal, window, softcap, qb, kb):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     q_pos: jax.Array, k_pos: jax.Array,
                     window: jax.Array | int = 0,
                     causal: bool = True,
                     softcap: float = 0.0, q_block: int = 512,
-                    k_block: int = 512) -> jax.Array:
+                    k_block: int = 512,
+                    scale: float | None = None) -> jax.Array:
     """Memory-O(L·block) attention.
 
     q: (B, Lq, H, D); k, v: (B, Lk, KV, D) with H % KV == 0;
     q_pos: (B, Lq) int32; k_pos: (B, Lk) int32 (INVALID_POS = masked slot).
     ``window`` may be a traced int32 scalar (0 = no sliding window).
+    ``scale`` multiplies the scores (None: ``D ** -0.5``).
     Returns (B, Lq, H, D).
     """
     o, _ = _flash_fwd_rule(q, k, v, q_pos, k_pos, window, causal, softcap,
-                           q_block, k_block)
+                           q_block, k_block, scale)
     return o
 
 
@@ -228,7 +231,7 @@ def _gqa_flatten(q, k, v, q_pos, k_pos):
 
 
 def _flash_fwd_rule(q, k, v, q_pos, k_pos, window, causal, softcap,
-                    q_block, k_block):
+                    q_block, k_block, scale):
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     qb = min(q_block, Lq)
@@ -241,12 +244,13 @@ def _flash_fwd_rule(q, k, v, q_pos, k_pos, window, causal, softcap,
     kf = _pad_to(kf, kb, 1)
     vf = _pad_to(vf, kb, 1)
     kpf = _pad_to(kpf, kb, kpf.ndim - 1, value=INVALID_POS)
-    of, lse = _fwd(qf, kf, vf, qpf, kpf, causal, window, softcap, qb, kb)
+    of, lse = _fwd(qf, kf, vf, qpf, kpf, causal, window, softcap, qb, kb,
+                   scale)
     o = of[:, :Lq].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
     return o, (q, k, v, q_pos, k_pos, window, o, lse[:, :Lq])
 
 
-def _flash_bwd_rule(causal, softcap, q_block, k_block, res, do):
+def _flash_bwd_rule(causal, softcap, q_block, k_block, scale, res, do):
     q, k, v, q_pos, k_pos, window, o, lse = res
     B, Lq, H, D = q.shape
     KV = k.shape[2]
@@ -266,7 +270,7 @@ def _flash_bwd_rule(causal, softcap, q_block, k_block, res, do):
     vf = _pad_to(vf, kb, 1)
     kpf = _pad_to(kpf, kb, kpf.ndim - 1, value=INVALID_POS)
     dqf, dkf, dvf = _bwd(qf, kf, vf, qpf, kpf, of, lsef, dof,
-                         causal, window, softcap, qb, kb)
+                         causal, window, softcap, qb, kb, scale)
     dq = dqf[:, :Lq].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
     dk = dkf[:, :Lk].reshape(B, KV, G, Lk, D).sum(axis=2)
     dk = dk.transpose(0, 2, 1, 3)

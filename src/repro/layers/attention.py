@@ -90,16 +90,18 @@ def make_mask(q_pos: jax.Array, k_pos: jax.Array, mode: str,
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array,
          mask: Optional[jax.Array] = None,
          logit_softcap: float = 0.0,
-         kv_valid: Optional[jax.Array] = None) -> jax.Array:
+         kv_valid: Optional[jax.Array] = None,
+         scale: Optional[float] = None) -> jax.Array:
     """q: (B, Lq, H, D); k, v: (B, Lk, KV, D); mask: (B?, Lq, Lk) bool.
 
     kv_valid: optional (B, Lk) bool marking valid cache slots (decode).
+    scale: the score scale (None: ``D ** -0.5``).
     Returns (B, Lq, H, D).
     """
     B, Lq, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     qg = q.reshape(B, Lq, KV, G, D)
     logits = jnp.einsum("blkgd,bskd->bklgs", qg.astype(jnp.float32) * scale,
                         k.astype(jnp.float32))          # (B, KV, Lq, G, Lk)
@@ -190,7 +192,8 @@ def _attend_views(q: jax.Array, k_view, v_view, *,
                   valid_len: Optional[jax.Array] = None,
                   kv_valid: Optional[jax.Array] = None,
                   logit_softcap: float = 0.0,
-                  window: "int | jax.Array" = 0) -> jax.Array:
+                  window: "int | jax.Array" = 0,
+                  scale: Optional[float] = None) -> jax.Array:
     """Dispatch one-token attention over a per-layer KVView pair
     (``repro.models.layouts``): the kernel consumes the PHYSICAL
     representation.
@@ -205,7 +208,8 @@ def _attend_views(q: jax.Array, k_view, v_view, *,
     * :class:`~repro.models.layouts.DenseView` — exactly the historic
       dense path (bit-identical to the pre-KVView code).
 
-    q: (B, 1, H, D) RoPE'd queries.  Returns (B, 1, H, D).
+    q: (B, 1, H, D) RoPE'd queries; ``scale`` the score scale (None:
+    ``D ** -0.5``).  Returns (B, 1, H, D).
     """
     from repro.kernels import ops
     from repro.models import layouts as LT
@@ -218,18 +222,19 @@ def _attend_views(q: jax.Array, k_view, v_view, *,
                 q[:, 0], k_view.storage.q, v_view.storage.q,
                 k_view.page_table, valid_len, softcap=logit_softcap,
                 window=window, k_scale=k_view.storage.scale,
-                v_scale=v_view.storage.scale)
+                v_scale=v_view.storage.scale, scale=scale)
         else:
             o = ops.paged_decode(
                 q[:, 0], k_view.storage.data.astype(dtype),
                 v_view.storage.data.astype(dtype), k_view.page_table,
-                valid_len, softcap=logit_softcap, window=window)
+                valid_len, softcap=logit_softcap, window=window,
+                scale=scale)
         return o[:, None]
     if isinstance(k_view, LT.QuantView) and valid_len is not None and \
             kv_valid is None and ops.int8_fused_available(window):
         o = ops.int8_decode_fused(q[:, 0], k_view.q, v_view.q,
                                   k_view.scale, v_view.scale, valid_len,
-                                  logit_softcap, window)
+                                  logit_softcap, window, scale)
         return o[:, None]
     k = k_view.dense().astype(dtype)
     v = v_view.dense().astype(dtype)
@@ -241,7 +246,7 @@ def _attend_views(q: jax.Array, k_view, v_view, *,
         kv_valid = jnp.logical_and(kv_valid,
                                    slots >= valid_len[:, None] - weff)
     return sdpa(q, k, v, mask=None, logit_softcap=logit_softcap,
-                kv_valid=kv_valid)
+                kv_valid=kv_valid, scale=scale)
 
 
 def decode_attend_view(params: Params, x: jax.Array, k_view, v_view,
@@ -250,14 +255,16 @@ def decode_attend_view(params: Params, x: jax.Array, k_view, v_view,
                        sin_q: Optional[jax.Array] = None,
                        logit_softcap: float = 0.0,
                        window: "int | jax.Array" = 0,
-                       live: Optional[jax.Array] = None):
+                       live: Optional[jax.Array] = None,
+                       scale: Optional[float] = None):
     """Layout-native one-token decode (:func:`decode_attend` over
     KVViews): project q/k/v for the new token, append K/V *through the
     view* (paged: only the owning page is touched; int8: the vector is
     quantized in place), attend over slots ``<= cache_len`` in the
     physical representation.  ``live`` (B,) bool masks the append by
     row (frozen rows write nothing; their output is garbage the caller
-    discards).  Returns (out (B,1,d), k_view, v_view)."""
+    discards); ``scale`` is the score scale (None: ``D ** -0.5``).
+    Returns (out (B,1,d), k_view, v_view)."""
     from repro.layers.rope import apply_rope
     dtype = x.dtype
     q, k_new, v_new = qkv_proj(params, x, x, dtype)
@@ -267,7 +274,8 @@ def decode_attend_view(params: Params, x: jax.Array, k_view, v_view,
     k_view = k_view.write_token(cache_len, k_new[:, 0], live)
     v_view = v_view.write_token(cache_len, v_new[:, 0], live)
     o = _attend_views(q, k_view, v_view, valid_len=cache_len + 1,
-                      logit_softcap=logit_softcap, window=window)
+                      logit_softcap=logit_softcap, window=window,
+                      scale=scale)
     return out_proj(params, o, dtype), k_view, v_view
 
 

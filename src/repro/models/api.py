@@ -283,6 +283,20 @@ class DecodeState:
         arrays only.  Compare against :meth:`dense_logical_bytes`."""
         return LT.view_touched_bytes(self.kv_views())
 
+    def state_bytes(self, length_axes: Dict[str, int]) -> Dict[str, int]:
+        """The slots' state split by kind: ``fixed``, the bytes of the kv
+        fields without a length axis (Mamba-2 ssm/conv, TConst's
+        windows), constant per slot however long its stream; ``growing``,
+        the bytes of the length-axis K/V that the live page tables
+        reference (a shared page once; the whole buffer when unpaged).
+        ``length_axes`` is the family's (``DecodeAPI._LENGTH_AXES``).
+        Host-side; concrete arrays."""
+        views = self.kv_views()
+        grow = {f: v for f, v in views.items() if f in length_axes}
+        fixed = {f: v for f, v in views.items() if f not in length_axes}
+        return {"fixed": LT.assigned_kv_bytes(fixed),
+                "growing": LT.assigned_kv_bytes(grow)}
+
     def assigned_kv_bytes(self) -> int:
         """KV bytes the live page tables actually reference: paged
         fields count their unique assigned pages — a prefix-SHARED page
@@ -1283,7 +1297,7 @@ class DenseDecode(DecodeAPI):
     def supports_speculative(self):
         # recurrent ssm/conv state advances through VERIFIED-BUT-REJECTED
         # tokens and cannot be rolled back by a length decrement
-        return self.cfg.arch_type != "ssm" and not self.cfg.hybrid_parallel
+        return not self.cfg.has_recurrent_state
 
     def verify_chunk(self, params, state, feed):
         with self._mesh_scope():
@@ -1302,7 +1316,7 @@ class DenseDecode(DecodeAPI):
         # ssm/conv recurrent state is a function of the FULL prompt and
         # cannot be reconstructed from adopted KV pages: recurrent
         # families forward from 0 (adopted pages still save writes/bytes)
-        if self.cfg.arch_type == "ssm" or self.cfg.hybrid_parallel:
+        if self.cfg.has_recurrent_state:
             return 0
         return resident_len
 

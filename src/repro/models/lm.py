@@ -10,6 +10,13 @@ Design notes
   ``window`` array riding the scan — masks are position arithmetic, so no
   unrolling is needed.  DeepSeek's leading dense layers differ in
   parameter *shape* and are unrolled separately (``dense_layers``).
+* **Interleaved kinds.**  A ``layer_pattern`` (granite-4.0-h: Mamba-2
+  and attention layers 9:1) keeps one stacked parameter tree and one
+  state buffer per kind (``mamba_layers`` with ``ssm``/``conv``,
+  ``attention_layers`` with ``k``/``v``) and walks the published order
+  as runs of like layers, one loop per run over that kind's stack
+  (:func:`layer_runs`), so the program grows with the runs, not the
+  layers.  A model of one kind is one run over ``layers``.
 * **Attention dispatch.**  Sequences longer than ``FLASH_THRESHOLD`` route
   through the blocked flash implementation (O(L·block) memory); short ones
   use the naive reference.  Both are numerically interchangeable (tested).
@@ -59,19 +66,118 @@ def layer_windows(cfg: ModelConfig) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# Layer kinds and runs (the published order past the leading dense layers)
+# ---------------------------------------------------------------------------
+
+
+def _n_dense(cfg: ModelConfig) -> int:
+    return cfg.first_dense_layers if cfg.is_moe else 0
+
+
+def stack_name(cfg: ModelConfig, kind: str) -> str:
+    """Parameter key of ``kind``'s stacked layers."""
+    return f"{kind}_layers" if cfg.layer_pattern else "layers"
+
+
+def kind_fields(cfg: ModelConfig, kind: str) -> Tuple[str, ...]:
+    """Cache fields a layer of ``kind`` reads and writes, each indexed
+    by the layer's place in its kind's stack."""
+    if kind == "mamba":
+        return ("ssm", "conv")
+    return ("k", "v") + (("ssm", "conv") if cfg.hybrid_parallel else ())
+
+
+def kind_counts(cfg: ModelConfig) -> Dict[str, int]:
+    """Stacked layers of each kind, in the order the kinds first appear."""
+    out: Dict[str, int] = {}
+    for kind in cfg.layer_kinds[_n_dense(cfg):]:
+        out[kind] = out.get(kind, 0) + 1
+    return out
+
+
+def layer_runs(cfg: ModelConfig) -> Tuple[Tuple[str, int, int], ...]:
+    """Maximal runs of like layers in the published order, as ``(kind,
+    first index in kind's stack, length)``: granite's first period is
+    ``(mamba, 0, 5), (attention, 0, 1), (mamba, 5, 4)``."""
+    runs: list = []
+    seen: Dict[str, int] = {}
+    for kind in cfg.layer_kinds[_n_dense(cfg):]:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return tuple(tuple(r) for r in runs)
+
+
+def kind_windows(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """Per-layer windows of each kind's stack, in stack order."""
+    w = layer_windows(cfg)[_n_dense(cfg):]
+    kinds = np.asarray(cfg.layer_kinds[_n_dense(cfg):])
+    return {k: jnp.asarray(w[kinds == k]) for k in kind_counts(cfg)}
+
+
+def _run_slice(tree: Any, start: int, count: int) -> Any:
+    """Leading-axis slice of a run's layers (the whole stack as is)."""
+    if start == 0 and count == jax.tree_util.tree_leaves(tree)[0].shape[0]:
+        return tree
+    return jax.tree_util.tree_map(lambda a: a[start:start + count], tree)
+
+
+def _join(parts: list) -> Any:
+    """Concatenate a kind's per-run stacked outputs in stack order."""
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *parts)
+
+
+def _branch(y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A mixer or MLP branch before its residual add (granite scales it)."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * cfg.residual_multiplier
+
+
+def _rope(x: jax.Array, cos, sin) -> jax.Array:
+    return x if cos is None else R.apply_rope(x, cos, sin)
+
+
+def _head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    logits = E.lm_head(params["embed"], x, cfg.logit_softcap)
+    if cfg.logits_scaling == 1.0:
+        return logits
+    return logits / cfg.logits_scaling
+
+
+def _embed(params: Params, tokens: jax.Array, cfg: ModelConfig
+           ) -> jax.Array:
+    return _scale_embed(
+        E.embed_tokens(params["embed"], tokens, jnp.dtype(cfg.dtype)), cfg)
+
+
+def _scale_embed(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    if cfg.embedding_multiplier == 1.0:
+        return x
+    return x * cfg.embedding_multiplier
+
+
+# ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
 
 
-def _init_layer(key: jax.Array, cfg: ModelConfig, moe: bool) -> Params:
+def _init_layer(key: jax.Array, cfg: ModelConfig, moe: bool,
+                kind: str = "attention") -> Params:
     ka, kf, ks = split_keys(key, 3)
     p: Params = {"ln1": init_rmsnorm(cfg.d_model, cfg.param_dtype)}
-    if cfg.arch_type == "ssm":
+    if kind == "mamba":
         p["ssm"] = S.init_ssm(ka, cfg)
-        return p
-    p["attn"] = A.init_attention(ka, cfg)
-    if cfg.hybrid_parallel:
-        p["ssm"] = S.init_ssm(ks, cfg)
+        if cfg.d_ff == 0:                # mamba2: mixer layers only
+            return p
+    else:
+        p["attn"] = A.init_attention(ka, cfg)
+        if cfg.hybrid_parallel:
+            p["ssm"] = S.init_ssm(ks, cfg)
     p["ln2"] = init_rmsnorm(cfg.d_model, cfg.param_dtype)
     if moe:
         p["ffn"] = init_moe(kf, cfg)
@@ -82,16 +188,18 @@ def _init_layer(key: jax.Array, cfg: ModelConfig, moe: bool) -> Params:
 
 def init_lm(key: jax.Array, cfg: ModelConfig) -> Params:
     ke, kd, kl = split_keys(key, 3)
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
-    n_scan = cfg.n_layers - n_dense
+    n_dense = _n_dense(cfg)
     params: Params = {"embed": E.init_embed(ke, cfg)}
     if n_dense:
         dkeys = split_keys(kd, n_dense)
         params["dense_layers"] = [
             _init_layer(k, cfg, moe=False) for k in dkeys]
-    lkeys = jax.random.split(kl, n_scan)
-    params["layers"] = jax.vmap(
-        lambda k: _init_layer(k, cfg, moe=cfg.is_moe))(lkeys)
+    counts = kind_counts(cfg)
+    kkeys = [kl] if len(counts) == 1 else split_keys(kl, len(counts))
+    for kk, (kind, n) in zip(kkeys, counts.items()):
+        params[stack_name(cfg, kind)] = jax.vmap(
+            lambda k, kind=kind: _init_layer(k, cfg, cfg.is_moe, kind))(
+                jax.random.split(kk, n))
     params["final_norm"] = init_rmsnorm(cfg.d_model, cfg.param_dtype)
     return params
 
@@ -107,16 +215,19 @@ def _self_attention(layer: Params, xn: jax.Array, pos: jax.Array,
     """pos: (L,) SHARED positions (1-D keeps flash masks head/batch-free)."""
     dtype = xn.dtype
     q, k, v = A.qkv_proj(layer["attn"], xn, xn, dtype)
-    q = R.apply_rope(q, cos, sin)
-    k = R.apply_rope(k, cos, sin)
-    L = xn.shape[1]
-    if L >= FLASH_THRESHOLD:
-        o = flash_attention(q, k, v, pos, pos, window, True,
-                            cfg.logit_softcap, 512, 512)
-    else:
-        mask = A.make_mask(pos, pos, "sliding", window)
-        o = A.sdpa(q, k, v, mask, cfg.logit_softcap)
-    return A.out_proj(layer["attn"], o, dtype)
+    q = _rope(q, cos, sin)
+    k = _rope(k, cos, sin)
+    return A.out_proj(layer["attn"], _attend_full(q, k, v, pos, window, cfg),
+                      dtype)
+
+
+def _attend_full(q, k, v, pos, window, cfg: ModelConfig) -> jax.Array:
+    """Causal (or windowed) attention over a whole sequence."""
+    if q.shape[1] >= FLASH_THRESHOLD:
+        return flash_attention(q, k, v, pos, pos, window, True,
+                               cfg.logit_softcap, 512, 512, cfg.attn_scale)
+    return A.sdpa(q, k, v, A.make_mask(pos, pos, "sliding", window),
+                  cfg.logit_softcap, scale=cfg.attn_scale)
 
 
 def _ffn(layer: Params, xn: jax.Array, cfg: ModelConfig, moe: bool
@@ -126,24 +237,29 @@ def _ffn(layer: Params, xn: jax.Array, cfg: ModelConfig, moe: bool
     return swiglu(layer["ffn"], xn), jnp.zeros((), jnp.float32)
 
 
+def _mlp_block(layer: Params, x: jax.Array, cfg: ModelConfig, moe: bool
+               ) -> Tuple[jax.Array, jax.Array]:
+    """The MLP after a mixer (none in a mixer-only mamba2 layer)."""
+    if "ffn" not in layer:
+        return x, jnp.zeros((), jnp.float32)
+    f, aux = _ffn(layer, rmsnorm(layer["ln2"], x, cfg.norm_eps), cfg, moe)
+    return x + _branch(f, cfg), aux
+
+
 def _layer_fwd(layer: Params, x: jax.Array, pos: jax.Array,
                window: jax.Array, cfg: ModelConfig, moe: bool,
-               cos: jax.Array, sin: jax.Array
+               cos: jax.Array, sin: jax.Array, kind: str = "attention"
                ) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence layer forward. Returns (x, aux)."""
-    eps = cfg.norm_eps
-    aux = jnp.zeros((), jnp.float32)
-    xn = rmsnorm(layer["ln1"], x, eps)
-    if cfg.arch_type == "ssm":
+    xn = rmsnorm(layer["ln1"], x, cfg.norm_eps)
+    if kind == "mamba":
         out, _ = S.ssm_mixer(layer["ssm"], xn, cfg)
-        return x + out, aux
-    out = _self_attention(layer, xn, pos, window, cfg, cos, sin)
-    if cfg.hybrid_parallel:
-        ssm_out, _ = S.ssm_mixer(layer["ssm"], xn, cfg)
-        out = (out + ssm_out) * 0.5          # hymba: mean-fuse parallel heads
-    x = x + out
-    f, aux = _ffn(layer, rmsnorm(layer["ln2"], x, eps), cfg, moe)
-    return x + f, aux
+    else:
+        out = _self_attention(layer, xn, pos, window, cfg, cos, sin)
+        if cfg.hybrid_parallel:
+            ssm_out, _ = S.ssm_mixer(layer["ssm"], xn, cfg)
+            out = (out + ssm_out) * 0.5      # hymba: mean-fuse parallel heads
+    return _mlp_block(layer, x + _branch(out, cfg), cfg, moe)
 
 
 # ---------------------------------------------------------------------------
@@ -162,11 +278,15 @@ def embed_inputs(params: Params, tokens: jax.Array, cfg: ModelConfig,
         idx = jnp.clip(jnp.cumsum(vision_mask, axis=1) - 1, 0, Tv - 1)
         gathered = jnp.take_along_axis(pv, idx[..., None], axis=1)
         x = jnp.where(vision_mask[..., None], gathered, x)
-    return x
+    return _scale_embed(x, cfg)
 
 
 def _rope_tables(cfg: ModelConfig, pos: jax.Array,
                  positions3: Optional[jax.Array]):
+    """cos/sin tables at ``pos``, or (None, None) with no positional
+    encoding (granite's NoPE attention)."""
+    if cfg.position_embedding == "nope":
+        return None, None
     hd = cfg.resolved_head_dim
     if cfg.mrope and cfg.mrope_sections:
         p3 = positions3 if positions3 is not None else R.text_positions3(pos)
@@ -189,46 +309,48 @@ def lm_forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     windows = jnp.asarray(layer_windows(cfg))
     aux = jnp.zeros((), jnp.float32)
 
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
     for i, layer in enumerate(params.get("dense_layers", [])):
         x, a = _layer_fwd(layer, x, pos, windows[i], cfg, False, cos, sin)
         aux = aux + a
 
-    def body(carry, xs):
-        x, aux = carry
-        layer, window = xs
-        x = shard_act(x)
-        x, a = _layer_fwd(layer, x, pos, window, cfg, cfg.is_moe, cos, sin)
-        x = shard_act(x)
-        return (x, aux + a), None
+    kwin = kind_windows(cfg)
+    for kind, start, n_scan in layer_runs(cfg):
+        def body(carry, xs, kind=kind):
+            x, aux = carry
+            layer, window = xs
+            x = shard_act(x)
+            x, a = _layer_fwd(layer, x, pos, window, cfg, cfg.is_moe, cos,
+                              sin, kind)
+            x = shard_act(x)
+            return (x, aux + a), None
 
-    n_scan = cfg.n_layers - n_dense
-    scan_xs = (params["layers"], windows[n_dense:])
-    group = _remat_group(n_scan) if remat else 1
-    if remat and group > 1:
-        # Nested (sqrt-depth) remat: only n_scan/group boundary activations
-        # are saved for the backward pass; each group's inner carries are
-        # recomputed from its boundary.  Cuts the 126-layer llama3 saved-
-        # activation footprint by ~9x (EXPERIMENTS.md §Perf iteration 2).
-        ng = n_scan // group
-        grouped = jax.tree_util.tree_map(
-            lambda a: a.reshape((ng, group) + a.shape[1:]), scan_xs)
+        scan_xs = _run_slice((params[stack_name(cfg, kind)], kwin[kind]),
+                             start, n_scan)
+        group = _remat_group(n_scan) if remat else 1
+        if remat and group > 1:
+            # Nested (sqrt-depth) remat: only n_scan/group boundary
+            # activations are saved for the backward pass; each group's
+            # inner carries are recomputed from its boundary.  Cuts the
+            # 126-layer llama3 saved-activation footprint by ~9x
+            # (EXPERIMENTS.md §Perf iteration 2).
+            ng = n_scan // group
+            grouped = jax.tree_util.tree_map(
+                lambda a: a.reshape((ng, group) + a.shape[1:]), scan_xs)
 
-        @jax.checkpoint
-        def group_body(carry, xs):
-            # barrier: stop XLA from hoisting f32 converts across the
-            # saved boundary stack (measured: it duplicated every saved
-            # carry in f32 — §Perf H1 it4)
-            carry = jax.lax.optimization_barrier(carry)
-            return jax.lax.scan(body, carry, xs)
+            @jax.checkpoint
+            def group_body(carry, xs, body=body):
+                # barrier: stop XLA from hoisting f32 converts across the
+                # saved boundary stack (measured: it duplicated every
+                # saved carry in f32 — §Perf H1 it4)
+                carry = jax.lax.optimization_barrier(carry)
+                return jax.lax.scan(body, carry, xs)
 
-        (x, aux), _ = jax.lax.scan(group_body, (x, aux), grouped)
-    else:
-        body_fn = jax.checkpoint(body) if remat else body
-        (x, aux), _ = jax.lax.scan(body_fn, (x, aux), scan_xs)
+            (x, aux), _ = jax.lax.scan(group_body, (x, aux), grouped)
+        else:
+            body_fn = jax.checkpoint(body) if remat else body
+            (x, aux), _ = jax.lax.scan(body_fn, (x, aux), scan_xs)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = E.lm_head(params["embed"], x, cfg.logit_softcap)
-    return logits, aux
+    return _head(params, x, cfg), aux
 
 
 def _remat_group(n: int) -> int:
@@ -264,32 +386,39 @@ QUANT_FIELDS = ("k", "v", "dense_k", "dense_v")
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int
                   ) -> Dict[str, Any]:
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
-    n_scan = cfg.n_layers - n_dense
+    """Zero cache: growing ``k``/``v`` (layers, batch, max_len, KV, hd)
+    over the attention layers, fixed ``ssm``/``conv`` recurrent state
+    over the layers with a Mamba-2 mixer; each field's leading axis is
+    its kind's stack (:func:`kind_fields`)."""
+    n_dense = _n_dense(cfg)
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     dt = jnp.dtype(cfg.dtype)
     cache: Dict[str, Any] = {"len": jnp.zeros((batch,), jnp.int32),
                              "done": jnp.zeros((batch,), bool)}
-    if cfg.arch_type != "ssm":
-        cache["k"] = jnp.zeros((n_scan, batch, max_len, kv, hd), dt)
-        cache["v"] = jnp.zeros((n_scan, batch, max_len, kv, hd), dt)
+    n_of = {}
+    for kind, n in kind_counts(cfg).items():
+        for f in kind_fields(cfg, kind):
+            n_of[f] = n
+    if cfg.has_growing_kv:
+        cache["k"] = jnp.zeros((n_of["k"], batch, max_len, kv, hd), dt)
+        cache["v"] = jnp.zeros((n_of["v"], batch, max_len, kv, hd), dt)
         if n_dense:
             cache["dense_k"] = jnp.zeros((n_dense, batch, max_len, kv, hd), dt)
             cache["dense_v"] = jnp.zeros((n_dense, batch, max_len, kv, hd), dt)
-    if cfg.arch_type == "ssm" or cfg.hybrid_parallel:
+    if cfg.has_recurrent_state:
         dims = S.ssm_dims(cfg)
         cache["ssm"] = jnp.zeros(
-            (n_scan, batch, dims.n_heads, dims.head_dim, dims.n_state),
+            (n_of["ssm"], batch, dims.n_heads, dims.head_dim, dims.n_state),
             jnp.float32)
         cache["conv"] = jnp.zeros(
-            (n_scan, batch, dims.d_conv - 1, dims.conv_dim), dt)
+            (n_of["conv"], batch, dims.d_conv - 1, dims.conv_dim), dt)
     return cache
 
 
 def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
                   cache_len: jax.Array, window: jax.Array, cfg: ModelConfig,
                   moe: bool, cos: jax.Array, sin: jax.Array,
-                  live: Optional[jax.Array] = None
+                  live: Optional[jax.Array] = None, kind: str = "attention"
                   ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Per-layer decode over KVViews: ``cache_slice`` holds the per-layer
     ``repro.models.layouts`` FieldViews, so the attention walks the
@@ -313,21 +442,21 @@ def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
         return out, {"ssm": LT.DenseView(new["ssm"]),
                      "conv": LT.DenseView(new["conv"])}
 
-    if cfg.arch_type == "ssm":
+    if kind == "mamba":
         out, st = run_ssm()
         new_slice.update(st)
-        return x + out, new_slice
-    out, k_view, v_view = A.decode_attend_view(
-        layer["attn"], xn, cache_slice["k"], cache_slice["v"], cache_len,
-        cos, sin, cfg.logit_softcap, window, live)
-    new_slice["k"], new_slice["v"] = k_view, v_view
-    if cfg.hybrid_parallel:
-        ssm_out, st = run_ssm()
-        new_slice.update(st)
-        out = (out + ssm_out) * 0.5
-    x = x + out
-    f, _ = _ffn(layer, rmsnorm(layer["ln2"], x, eps), cfg, moe)
-    return x + f, new_slice
+    else:
+        out, k_view, v_view = A.decode_attend_view(
+            layer["attn"], xn, cache_slice["k"], cache_slice["v"],
+            cache_len, cos, sin, cfg.logit_softcap, window, live,
+            cfg.attn_scale)
+        new_slice["k"], new_slice["v"] = k_view, v_view
+        if cfg.hybrid_parallel:
+            ssm_out, st = run_ssm()
+            new_slice.update(st)
+            out = (out + ssm_out) * 0.5
+    x, _ = _mlp_block(layer, x + _branch(out, cfg), cfg, moe)
+    return x, new_slice
 
 
 def lm_decode_step_views(params: Params, cache: Dict[str, Any],
@@ -342,13 +471,10 @@ def lm_decode_step_views(params: Params, cache: Dict[str, Any],
     ``live`` (B,) bool masks every write by row: a frozen row's state
     comes through bit-identical (None: every row steps).
     token: (B,) -> (logits (B, V), cache)."""
-    B = token.shape[0]
-    dtype = jnp.dtype(cfg.dtype)
-    x = E.embed_tokens(params["embed"], token[:, None], dtype)
+    x = _embed(params, token[:, None], cfg)
     pos = cache["len"][:, None]
     cos, sin = _rope_tables(cfg, pos, positions3)
     windows = jnp.asarray(layer_windows(cfg))
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
 
     cache = dict(cache)
     for i, layer in enumerate(params.get("dense_layers", [])):
@@ -363,31 +489,32 @@ def lm_decode_step_views(params: Params, cache: Dict[str, Any],
     # as its output (measured: ~2x decode peak on llama3-405b decode_32k,
     # EXPERIMENTS.md §Beyond-paper).  Views are registered pytrees, so
     # they ride the carry; ``set_layer`` writes only layer i's slice of
-    # the physical buffers.
-    keys = []
-    if cfg.arch_type != "ssm":
-        keys += ["k", "v"]
-    if cfg.arch_type == "ssm" or cfg.hybrid_parallel:
-        keys += ["ssm", "conv"]
-    scan_windows = jnp.asarray(windows[n_dense:])
+    # the physical buffers.  One loop per run of like layers, over its
+    # kind's stack; the scopes name each run's ops in the profile.
+    kwin = kind_windows(cfg)
+    for kind, start, n in layer_runs(cfg):
+        keys = kind_fields(cfg, kind)
+        stack, scan_windows = params[stack_name(cfg, kind)], kwin[kind]
 
-    def body(i, carry):
-        x, bufs = carry
-        layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-        slc = {k: bufs[k].layer(i) for k in keys}
-        x, new = _layer_decode(layer, x, slc, cache["len"],
-                               scan_windows[i], cfg, cfg.is_moe, cos, sin,
-                               live)
-        bufs = {k: bufs[k].set_layer(i, new[k]) for k in keys}
-        return (x, bufs)
+        def body(i, carry, kind=kind, keys=keys, stack=stack,
+                 scan_windows=scan_windows):
+            x, bufs = carry
+            layer = jax.tree_util.tree_map(lambda a: a[i], stack)
+            slc = {k: bufs[k].layer(i) for k in keys}
+            x, new = _layer_decode(layer, x, slc, cache["len"],
+                                   scan_windows[i], cfg, cfg.is_moe, cos,
+                                   sin, live, kind)
+            bufs = {k: bufs[k].set_layer(i, new[k]) for k in keys}
+            return (x, bufs)
 
-    n_scan = cfg.n_layers - n_dense
-    x, bufs = jax.lax.fori_loop(
-        0, n_scan, body, (x, {k: cache[k] for k in keys}))
-    for k in keys:
-        cache[k] = bufs[k]
+        scope = "decode.mamba" if kind == "mamba" else "decode.attn"
+        with jax.named_scope(scope):
+            x, bufs = jax.lax.fori_loop(
+                start, start + n, body, (x, {k: cache[k] for k in keys}))
+        for k in keys:
+            cache[k] = bufs[k]
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = E.lm_head(params["embed"], x, cfg.logit_softcap)[:, 0]
+    logits = _head(params, x, cfg)[:, 0]
     cache["len"] = cache["len"] + (
         1 if live is None else live.astype(jnp.int32))
     return logits, cache
@@ -429,23 +556,22 @@ def lm_verify_chunk_views(params: Params, cache: Dict[str, Any],
     Returns (logits (B, C, V), cache — counters untouched).
     """
     from repro.kernels import ops
-    assert cfg.arch_type != "ssm" and not cfg.hybrid_parallel, \
+    assert not cfg.has_recurrent_state, \
         "recurrent state cannot be rolled back by a length decrement"
     B, C = feed.shape
     dtype = jnp.dtype(cfg.dtype)
     eps = cfg.norm_eps
-    x = E.embed_tokens(params["embed"], feed, dtype)             # (B, C, D)
+    x = _embed(params, feed, cfg)                                # (B, C, D)
     pos = cache["len"][:, None] + \
         jnp.arange(C, dtype=jnp.int32)[None]                     # (B, C)
     cos, sin = _rope_tables(cfg, pos, None)
     windows = jnp.asarray(layer_windows(cfg))
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
 
     def layer_verify(layer, x, kv, vv, window, moe):
         xn = rmsnorm(layer["ln1"], x, eps)
         q, k_new, v_new = A.qkv_proj(layer["attn"], xn, xn, dtype)
-        q = R.apply_rope(q, cos, sin)
-        k_new = R.apply_rope(k_new, cos, sin)
+        q = _rope(q, cos, sin)
+        k_new = _rope(k_new, cos, sin)
         for c in range(C):
             kv = kv.write_token(cache["len"] + c, k_new[:, c])
             vv = vv.write_token(cache["len"] + c, v_new[:, c])
@@ -453,10 +579,10 @@ def lm_verify_chunk_views(params: Params, cache: Dict[str, Any],
         kpos = jnp.arange(kd.shape[1], dtype=jnp.int32)
         o = ops.prefill_chunk_attention(q, kd, vv.dense().astype(dtype),
                                         pos, kpos, window,
-                                        cfg.logit_softcap)
-        x = x + A.out_proj(layer["attn"], o, dtype)
-        f, _ = _ffn(layer, rmsnorm(layer["ln2"], x, eps), cfg, moe)
-        return x + f, kv, vv
+                                        cfg.logit_softcap, cfg.attn_scale)
+        x = x + _branch(A.out_proj(layer["attn"], o, dtype), cfg)
+        x, _ = _mlp_block(layer, x, cfg, moe)
+        return x, kv, vv
 
     cache = dict(cache)
     for i, layer in enumerate(params.get("dense_layers", [])):
@@ -466,20 +592,21 @@ def lm_verify_chunk_views(params: Params, cache: Dict[str, Any],
         cache["dense_k"] = cache["dense_k"].set_layer(i, nk)
         cache["dense_v"] = cache["dense_v"].set_layer(i, nv)
 
-    scan_windows = jnp.asarray(windows[n_dense:])
+    scan_windows = kind_windows(cfg)["attention"]
+    stack = params[stack_name(cfg, "attention")]
 
     def body(i, carry):
         x, kb, vb = carry
-        layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
+        layer = jax.tree_util.tree_map(lambda a: a[i], stack)
         x, nk, nv = layer_verify(layer, x, kb.layer(i), vb.layer(i),
                                  scan_windows[i], cfg.is_moe)
         return (x, kb.set_layer(i, nk), vb.set_layer(i, nv))
 
     x, kb, vb = jax.lax.fori_loop(
-        0, cfg.n_layers - n_dense, body, (x, cache["k"], cache["v"]))
+        0, kind_counts(cfg)["attention"], body, (x, cache["k"], cache["v"]))
     cache["k"], cache["v"] = kb, vb
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = E.lm_head(params["embed"], x, cfg.logit_softcap)   # (B, C, V)
+    logits = _head(params, x, cfg)                              # (B, C, V)
     return logits, cache
 
 
@@ -512,44 +639,42 @@ def lm_prefill_chunk(params: Params, row: Dict[str, Any],
     B, C = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
     eps = cfg.norm_eps
-    x = shard_act(E.embed_tokens(params["embed"], tokens, dtype))
+    x = shard_act(_embed(params, tokens, cfg))
     pos = start + jnp.arange(C, dtype=jnp.int32)
     cos, sin = _rope_tables(cfg, pos, None)
     windows = jnp.asarray(layer_windows(cfg))
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
-    has_attn = cfg.arch_type != "ssm"
-    has_ssm = cfg.arch_type == "ssm" or cfg.hybrid_parallel
     # real tokens in THIS chunk (the last chunk is zero-padded)
     in_chunk = jnp.clip(n_valid - start, 0, C)
     vl = jnp.broadcast_to(in_chunk, (B,))
     row = dict(row)
 
     def chunk_layer(layer, x, window, moe, k_row=None, v_row=None,
-                    ssm_st=None):
+                    ssm_st=None, kind="attention"):
         xn = rmsnorm(layer["ln1"], x, eps)
         new_st = None
         ssm_out = None
         if ssm_st is not None:
             ssm_out, new_st = S.ssm_mixer(layer["ssm"], xn, cfg,
                                           state=ssm_st, valid_len=vl)
-        if not has_attn:
-            return x + ssm_out, None, None, None, None, new_st
+        if kind == "mamba":
+            x, _ = _mlp_block(layer, x + _branch(ssm_out, cfg), cfg, moe)
+            return x, None, None, None, None, new_st
         q, k, v = A.qkv_proj(layer["attn"], xn, xn, dtype)
-        q = R.apply_rope(q, cos, sin)
-        k = R.apply_rope(k, cos, sin)
+        q = _rope(q, cos, sin)
+        k = _rope(k, cos, sin)
         k_row = jax.lax.dynamic_update_slice_in_dim(
             k_row, k.astype(k_row.dtype), start, axis=1)
         v_row = jax.lax.dynamic_update_slice_in_dim(
             v_row, v.astype(v_row.dtype), start, axis=1)
         kpos = jnp.arange(k_row.shape[1], dtype=jnp.int32)
         o = ops.prefill_chunk_attention(q, k_row, v_row, pos, kpos,
-                                        window, cfg.logit_softcap)
+                                        window, cfg.logit_softcap,
+                                        cfg.attn_scale)
         out = A.out_proj(layer["attn"], o, dtype)
         if cfg.hybrid_parallel:
             out = (out + ssm_out) * 0.5
-        x = x + out
-        f, _ = _ffn(layer, rmsnorm(layer["ln2"], x, eps), cfg, moe)
-        return x + f, k_row, v_row, k, v, new_st
+        x, _ = _mlp_block(layer, x + _branch(out, cfg), cfg, moe)
+        return x, k_row, v_row, k, v, new_st
 
     chunk_kv: Dict[str, Any] = {}
     dk_c, dv_c = [], []
@@ -565,33 +690,40 @@ def lm_prefill_chunk(params: Params, row: Dict[str, Any],
         chunk_kv["dense_k"] = jnp.stack(dk_c)
         chunk_kv["dense_v"] = jnp.stack(dv_c)
 
-    xs: Dict[str, Any] = {"layer": params["layers"],
-                          "window": windows[n_dense:]}
-    if has_attn:
-        xs["k"], xs["v"] = row["k"], row["v"]
-    if has_ssm:
-        xs["ssm"], xs["conv"] = row["ssm"], row["conv"]
+    kwin = kind_windows(cfg)
+    parts: Dict[str, list] = {}
+    for kind, start_i, n in layer_runs(cfg):
+        fields = kind_fields(cfg, kind)
+        has_ssm = "ssm" in fields
+        xs: Dict[str, Any] = {"layer": params[stack_name(cfg, kind)],
+                              "window": kwin[kind]}
+        xs.update({f: row[f] for f in fields})
+        xs = _run_slice(xs, start_i, n)
 
-    def body(x, xs_i):
-        st = {"ssm": xs_i["ssm"], "conv": xs_i["conv"]} if has_ssm else None
-        x, kr, vr, kc, vc, new_st = chunk_layer(
-            xs_i["layer"], shard_act(x), xs_i["window"], cfg.is_moe,
-            xs_i.get("k"), xs_i.get("v"), st)
-        ys = {}
-        if has_attn:
-            ys.update(k=kr, v=vr, kc=kc, vc=vc)
-        if has_ssm:
-            ys.update(ssm=new_st["ssm"], conv=new_st["conv"])
-        return x, ys
+        def body(x, xs_i, kind=kind, has_ssm=has_ssm):
+            st = {"ssm": xs_i["ssm"], "conv": xs_i["conv"]} if has_ssm \
+                else None
+            x, kr, vr, kc, vc, new_st = chunk_layer(
+                xs_i["layer"], shard_act(x), xs_i["window"], cfg.is_moe,
+                xs_i.get("k"), xs_i.get("v"), st, kind)
+            ys = {}
+            if kind == "attention":
+                ys.update(k=kr, v=vr, kc=kc, vc=vc)
+            if has_ssm:
+                ys.update(ssm=new_st["ssm"], conv=new_st["conv"])
+            return x, ys
 
-    x, ys = jax.lax.scan(body, x, xs)
-    if has_attn:
-        row["k"], row["v"] = ys["k"], ys["v"]
+        x, ys = jax.lax.scan(body, x, xs)
+        for f, y in ys.items():
+            parts.setdefault(f, []).append(y)
+    ys = {f: _join(p) for f, p in parts.items()}
+    for f in ("k", "v", "ssm", "conv"):
+        if f in ys:
+            row[f] = ys[f]
+    if "kc" in ys:
         chunk_kv["k"], chunk_kv["v"] = ys["kc"], ys["vc"]
-    if has_ssm:
-        row["ssm"], row["conv"] = ys["ssm"], ys["conv"]
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = E.lm_head(params["embed"], x, cfg.logit_softcap)
+    logits = _head(params, x, cfg)
     return logits, row, chunk_kv
 
 
@@ -613,55 +745,56 @@ def lm_prefill(params: Params, tokens: jax.Array, cfg: ModelConfig,
     pos = jnp.arange(L, dtype=jnp.int32)          # shared 1-D positions
     cos, sin = _rope_tables(cfg, pos, positions3)
     windows = jnp.asarray(layer_windows(cfg))
-    n_dense = cfg.first_dense_layers if cfg.is_moe else 0
     cache = init_kv_cache(cfg, B, max_len)
     eps = cfg.norm_eps
     dtype = jnp.dtype(cfg.dtype)
 
-    def capture_layer(layer, x, window, moe):
+    def capture_layer(layer, x, window, moe, kind="attention"):
         """Layer fwd that also returns this layer's K/V (and ssm state)."""
         out_extras: Dict[str, Any] = {}
         xn = rmsnorm(layer["ln1"], x, eps)
-        if cfg.arch_type == "ssm" or cfg.hybrid_parallel:
+        if "ssm" in kind_fields(cfg, kind):
             st0 = {"ssm": jnp.zeros_like(cache["ssm"][0]),
                    "conv": jnp.zeros_like(cache["conv"][0])}
             ssm_out, st = S.ssm_mixer(layer["ssm"], xn, cfg, state=st0)
             out_extras["ssm"], out_extras["conv"] = st["ssm"], st["conv"]
-        if cfg.arch_type == "ssm":
-            return x + ssm_out, out_extras
+        if kind == "mamba":
+            x, _ = _mlp_block(layer, x + _branch(ssm_out, cfg), cfg, moe)
+            return x, out_extras
         q, k, v = A.qkv_proj(layer["attn"], xn, xn, dtype)
-        q = R.apply_rope(q, cos, sin)
-        k = R.apply_rope(k, cos, sin)
+        q = _rope(q, cos, sin)
+        k = _rope(k, cos, sin)
         kf = jnp.zeros((B, max_len) + k.shape[2:], dtype)
         vf = jnp.zeros((B, max_len) + v.shape[2:], dtype)
         out_extras["k"] = jax.lax.dynamic_update_slice_in_dim(kf, k, 0, 1)
         out_extras["v"] = jax.lax.dynamic_update_slice_in_dim(vf, v, 0, 1)
-        if L >= FLASH_THRESHOLD:
-            o = flash_attention(q, k, v, pos, pos, window, True,
-                                cfg.logit_softcap, 512, 512)
-        else:
-            o = A.sdpa(q, k, v, A.make_mask(pos, pos, "sliding", window),
-                       cfg.logit_softcap)
-        out = A.out_proj(layer["attn"], o, dtype)
+        out = A.out_proj(layer["attn"],
+                         _attend_full(q, k, v, pos, window, cfg), dtype)
         if cfg.hybrid_parallel:
             out = (out + ssm_out) * 0.5
-        x = x + out
-        f, _ = _ffn(layer, rmsnorm(layer["ln2"], x, eps), cfg, moe)
-        return x + f, out_extras
+        x, _ = _mlp_block(layer, x + _branch(out, cfg), cfg, moe)
+        return x, out_extras
 
     for i, layer in enumerate(params.get("dense_layers", [])):
         x, ex = capture_layer(layer, x, windows[i], False)
         cache["dense_k"] = cache["dense_k"].at[i].set(ex["k"])
         cache["dense_v"] = cache["dense_v"].at[i].set(ex["v"])
 
-    def body(x, xs):
-        layer, window = xs
-        return capture_layer(layer, shard_act(x), window, cfg.is_moe)
+    kwin = kind_windows(cfg)
+    parts: Dict[str, list] = {}
+    for kind, start, n in layer_runs(cfg):
+        def body(x, xs, kind=kind):
+            layer, window = xs
+            return capture_layer(layer, shard_act(x), window, cfg.is_moe,
+                                 kind)
 
-    x, extras = jax.lax.scan(body, x, (params["layers"], windows[n_dense:]))
-    for key, val in extras.items():
-        cache[key] = val
+        x, extras = jax.lax.scan(body, x, _run_slice(
+            (params[stack_name(cfg, kind)], kwin[kind]), start, n))
+        for key, val in extras.items():
+            parts.setdefault(key, []).append(val)
+    for key, val in parts.items():
+        cache[key] = _join(val)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = E.lm_head(params["embed"], x[:, -1:], cfg.logit_softcap)[:, 0]
+    logits = _head(params, x[:, -1:], cfg)[:, 0]
     cache["len"] = jnp.full((B,), L, jnp.int32)
     return logits, cache

@@ -358,6 +358,14 @@ class SlotScheduler:
         tracks logical — global — pages)."""
         return self.state.assigned_kv_bytes()
 
+    def state_bytes(self) -> Dict[str, int]:
+        """The slots' state in two counters: ``fixed`` (recurrent
+        ssm/conv state and other per-slot buffers whose size does not
+        grow with the stream) and ``growing`` (the K/V pages the live
+        tables reference); see ``DecodeState.state_bytes``.  Their sum
+        is ``assigned_kv_bytes``."""
+        return self.state.state_bytes(self.decode._LENGTH_AXES)
+
     def per_device_kv_bytes(self) -> int:
         """Largest per-device share of the physical KV buffers —
         ≈ ``kv_bytes() / model_shards`` for the head-sharded decode

@@ -149,3 +149,70 @@ def test_ssd_chunked_matches_stepwise_recurrence():
     np.testing.assert_allclose(np.asarray(y_seq), np.asarray(y_chk),
                                atol=2e-4)
     np.testing.assert_allclose(np.asarray(st), np.asarray(st_chk), atol=2e-4)
+
+
+# granite scales attention scores by 1/64 at head_dim 64, not 1/8: every
+# attention kernel takes the scale as an argument (None: D ** -0.5)
+SCALE = 0.015625
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_paged_decode_at_a_non_default_scale(impl):
+    from repro.kernels.paged_decode_attention import (
+        paged_decode_attention_pallas, paged_decode_attention_xla)
+    B, S, H, KV, D, page = 3, 48, 8, 2, 64, 16
+    ks = jax.random.split(KEY, 4)
+    pps = S // page
+    q = 4.0 * jax.random.normal(ks[0], (B, H, D), jnp.float32)
+    pool_k = jax.random.normal(ks[1], (B * pps + 1, page, KV, D))
+    pool_v = jax.random.normal(ks[2], (B * pps + 1, page, KV, D))
+    pt = jax.random.permutation(ks[3], B * pps).reshape(B, pps).astype(
+        jnp.int32)
+    vl = jnp.asarray([5, 48, 30], jnp.int32)
+    if impl == "pallas":
+        o = paged_decode_attention_pallas(q, pool_k, pool_v, pt, vl,
+                                          scale=SCALE, interpret=True)
+    else:
+        o = paged_decode_attention_xla(q, pool_k, pool_v, pt, vl,
+                                       scale=SCALE)
+    k = jnp.take(pool_k, pt, axis=0).reshape(B, S, KV, D)
+    v = jnp.take(pool_v, pt, axis=0).reshape(B, S, KV, D)
+    o_ref = REF.decode_reference(q, k, v, vl, scale=SCALE)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    o_default = REF.decode_reference(q, k, v, vl)
+    assert float(jnp.max(jnp.abs(o_ref - o_default))) > 1e-2
+
+
+def test_dense_decode_at_a_non_default_scale():
+    B, S, H, KV, D = 2, 64, 8, 2, 64
+    ks = jax.random.split(KEY, 3)
+    q = 4.0 * jax.random.normal(ks[0], (B, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32)
+    vl = jnp.asarray([17, 64], jnp.int32)
+    o = decode_attention_pallas(q, k, v, vl, scale=SCALE, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(REF.decode_reference(q, k, v, vl,
+                                                       scale=SCALE)),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla", "chunk"])
+def test_prefill_attention_at_a_non_default_scale(impl):
+    from repro.kernels import ops
+    B, Lq, Lk, H, KV, D = 1, 64, 128, 8, 2, 64
+    q, k, v = _qkv(B, Lq, Lk, H, KV, D, jnp.float32)
+    q = 4.0 * q
+    qp = jnp.broadcast_to(jnp.arange(Lk - Lq, Lk), (B, Lq)).astype(jnp.int32)
+    kp = jnp.broadcast_to(jnp.arange(Lk), (B, Lk)).astype(jnp.int32)
+    if impl == "pallas":
+        o = flash_attention_fwd_pallas(q, k, v, qp, kp, block_q=32,
+                                       block_k=32, scale=SCALE,
+                                       interpret=True)
+    elif impl == "xla":
+        o = flash_attention(q, k, v, qp, kp, 0, True, 0.0, 32, 32, SCALE)
+    else:
+        o = ops.prefill_chunk_attention(q, k, v, qp[0], kp[0], 0, 0.0,
+                                        SCALE)
+    o_ref = REF.mha_reference(q, k, v, qp, kp, scale=SCALE)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
