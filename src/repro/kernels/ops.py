@@ -33,6 +33,7 @@ from repro.kernels.flash_attention import flash_attention_fwd_pallas
 from repro.kernels.paged_decode_attention import (
     paged_decode_attention_pallas, paged_decode_attention_xla)
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.kernels.ssd_state_update import ssd_state_update_pallas
 
 
 def _pallas_enabled() -> bool:
@@ -377,3 +378,64 @@ def ssd_scan(x, dt, a, b, c, chunk, init_state=None):
                                interpret=_interpret())
     from repro.layers.ssm import ssd_chunked
     return ssd_chunked(x, dt, a, b, c, chunk, init_state)
+
+
+# ---------------------------------------------------------------------------
+# SSD decode step, in place in the state stack
+# ---------------------------------------------------------------------------
+
+
+def _ssd_state_update_impl(stack, i, x, dt, a, b, c, live):
+    if _pallas_enabled():
+        f32 = jnp.float32
+        dec = jnp.exp(dt.astype(f32) * a.astype(f32)[None])
+        xdt = x.astype(f32) * dt.astype(f32)[..., None]
+        y, stack = ssd_state_update_pallas(stack, i, dec, xdt, b, c, live,
+                                           interpret=_interpret())
+        return y.astype(x.dtype), stack
+    from repro.layers.common import where_rows
+    from repro.layers.ssm import ssd_step
+    st = jax.lax.dynamic_index_in_dim(stack, i, 0, keepdims=False)
+    y, new = ssd_step(st, x, dt, a, b, c)
+    if live is not None:
+        new = where_rows(live, new, st, 0)
+    return y, jax.lax.dynamic_update_index_in_dim(
+        stack, new.astype(stack.dtype), i, 0)
+
+
+def ssd_state_update(stack: jax.Array, i: jax.Array, x: jax.Array,
+                     dt: jax.Array, a: jax.Array, b: jax.Array,
+                     c: jax.Array, live: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """One recurrent decode step of layer ``i``'s state inside its stack
+    (L, B, H, P, N); x: (B, H, P); dt: (B, H); a: (H,); b, c: (B, N);
+    ``live`` (B,) bool freezes rows (None: every row steps).  Returns
+    (y (B, H, P), the stack with layer i stepped).
+
+    The Pallas path reads and writes the layer's state once, in place;
+    the XLA path is ``ssd_step`` on the sliced layer, the per-row
+    select and the write-back.  Under a decode-mesh scope the kernel
+    runs inside shard_map: slots over data, heads over model (the
+    stack's own split, ``repro.sharding.rules``); the XLA path is left
+    to the partitioner, as before."""
+    mesh = _decode_mesh()
+    if mesh is not None and _pallas_enabled():
+        db, mb = _mesh_axes(mesh, batch=x.shape[0], heads=(x.shape[1],))
+        if db is not None or mb is not None:
+            operands: Dict[str, Any] = dict(stack=stack, i=jnp.asarray(i),
+                                            x=x, dt=dt, a=a, b=b, c=c)
+            specs: Dict[str, P] = dict(
+                stack=P(None, db, mb, None, None), i=P(), x=P(db, mb, None),
+                dt=P(db, mb), a=P(mb), b=P(db, None), c=P(db, None))
+            if live is not None:
+                operands["live"] = live
+                specs["live"] = P(db)
+
+            def inner(o):
+                return _ssd_state_update_impl(
+                    o["stack"], o["i"], o["x"], o["dt"], o["a"], o["b"],
+                    o["c"], o.get("live"))
+
+            return _shard_mapped(inner, mesh, (specs,),
+                                 (P(db, mb, None), specs["stack"]))(operands)
+    return _ssd_state_update_impl(stack, i, x, dt, a, b, c, live)

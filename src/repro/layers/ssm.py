@@ -203,6 +203,8 @@ def ssm_mixer(params: Params, x: jax.Array, cfg: ModelConfig,
               d_model: Optional[int] = None,
               state: Optional[dict] = None,
               valid_len: Optional[jax.Array] = None,
+              layer: Optional[jax.Array] = None,
+              live: Optional[jax.Array] = None,
               ) -> Tuple[jax.Array, Optional[dict]]:
     """Mamba2 mixer. x: (B, L, d). If ``state`` is given (keys: ssm, conv),
     runs in stepwise/streaming mode and returns the updated state.
@@ -213,7 +215,13 @@ def ssm_mixer(params: Params, x: jax.Array, cfg: ModelConfig,
     zero input contribution), and the conv context window ends at
     ``valid_len`` — so the returned state is the state after the REAL
     tokens, bit-for-bit.  Padding rows' outputs are garbage (discarded
-    by the caller)."""
+    by the caller).
+
+    layer: a single-token step's index into a stacked state: ``state
+    ["ssm"]`` is then the whole (layers, B, H, P, N) stack, layer
+    ``layer`` is stepped in place for the rows ``live`` (B,) bool marks
+    (None: every row) and the whole stack is returned
+    (:func:`repro.kernels.ops.ssd_state_update`)."""
     from repro.sharding.rules import shard_act
     dims = ssm_dims(cfg, d_model)
     dtype = x.dtype
@@ -236,7 +244,14 @@ def ssm_mixer(params: Params, x: jax.Array, cfg: ModelConfig,
     b = xbc[..., dims.d_inner:dims.d_inner + dims.n_state]
     c = xbc[..., dims.d_inner + dims.n_state:]
 
-    if state is not None and L == 1:
+    if layer is not None:
+        from repro.kernels import ops
+        assert state is not None and L == 1, "a stacked state steps one token"
+        y, new_ssm = ops.ssd_state_update(state["ssm"], layer, xs[:, 0],
+                                          dt[:, 0], a, b[:, 0], c[:, 0],
+                                          live)
+        y = y[:, None]
+    elif state is not None and L == 1:
         y, new_ssm = ssd_step(state["ssm"], xs[:, 0], dt[:, 0], a,
                               b[:, 0], c[:, 0])
         y = y[:, None]

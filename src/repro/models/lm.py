@@ -382,6 +382,10 @@ CACHE_BATCH_AXES = {
 # requantizing it would accumulate error — it stays dense).
 LENGTH_AXES = {"k": 2, "v": 2, "dense_k": 2, "dense_v": 2}
 QUANT_FIELDS = ("k", "v", "dense_k", "dense_v")
+# Fields a decode step updates in place in their kind's stack: the layer
+# loop hands them over whole, with the layer index, and writes back what
+# comes out (no slice of the layer, no ``set_layer``).
+IN_PLACE_FIELDS = ("ssm",)
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int
@@ -418,29 +422,33 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int
 def _layer_decode(layer: Params, x: jax.Array, cache_slice: Dict[str, Any],
                   cache_len: jax.Array, window: jax.Array, cfg: ModelConfig,
                   moe: bool, cos: jax.Array, sin: jax.Array,
-                  live: Optional[jax.Array] = None, kind: str = "attention"
+                  live: Optional[jax.Array] = None, kind: str = "attention",
+                  index: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Per-layer decode over KVViews: ``cache_slice`` holds the per-layer
     ``repro.models.layouts`` FieldViews, so the attention walks the
-    physical representation (paged pool / int8) directly.  ``live``
-    (B,) bool freezes rows at the write: the K/V append is masked, and
-    the recurrent state, which a step rewrites whole, is selected per
-    row — O(1) a row."""
+    physical representation (paged pool / int8) directly, and for the
+    :data:`IN_PLACE_FIELDS` the whole stack, stepped at ``index``.
+    ``live`` (B,) bool freezes rows at the write: the K/V append is
+    masked, and the recurrent state, which a step rewrites whole, is
+    selected per row — O(1) a row."""
     from repro.models import layouts as LT
     eps = cfg.norm_eps
     new_slice: Dict[str, Any] = {}
     xn = rmsnorm(layer["ln1"], x, eps)
 
     def run_ssm():
-        st = {"ssm": cache_slice["ssm"].dense(),
-              "conv": cache_slice["conv"].dense()}
-        out, new = S.ssm_mixer(layer["ssm"], xn, cfg, state=st)
+        conv = cache_slice["conv"].dense()
+        out, new = S.ssm_mixer(layer["ssm"], xn, cfg,
+                               state={"ssm": cache_slice["ssm"].dense(),
+                                      "conv": conv},
+                               layer=index, live=live)
         if live is not None:
-            new = {k: where_rows(live, new[k], st[k], 0) for k in new}
+            new["conv"] = where_rows(live, new["conv"], conv, 0)
         # the recurrent state is never quantized/paged (mutated every
         # step), so a fresh DenseView is the identity re-wrap
-        return out, {"ssm": LT.DenseView(new["ssm"]),
-                     "conv": LT.DenseView(new["conv"])}
+        return out, {k: LT.DenseView(new[k], cache_slice[k].batch_axis)
+                     for k in new}
 
     if kind == "mamba":
         out, st = run_ssm()
@@ -500,11 +508,13 @@ def lm_decode_step_views(params: Params, cache: Dict[str, Any],
                  scan_windows=scan_windows):
             x, bufs = carry
             layer = jax.tree_util.tree_map(lambda a: a[i], stack)
-            slc = {k: bufs[k].layer(i) for k in keys}
+            slc = {k: bufs[k] if k in IN_PLACE_FIELDS else bufs[k].layer(i)
+                   for k in keys}
             x, new = _layer_decode(layer, x, slc, cache["len"],
                                    scan_windows[i], cfg, cfg.is_moe, cos,
-                                   sin, live, kind)
-            bufs = {k: bufs[k].set_layer(i, new[k]) for k in keys}
+                                   sin, live, kind, i)
+            bufs = {k: new[k] if k in IN_PLACE_FIELDS
+                    else bufs[k].set_layer(i, new[k]) for k in keys}
             return (x, bufs)
 
         scope = "decode.mamba" if kind == "mamba" else "decode.attn"

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import runtime
 from repro.config import get_config
 from repro.models import layouts as LT
 from repro.models.api import build_decode, decode_chunk
@@ -160,8 +161,8 @@ def test_scheduler_streams_follow_reference(granite, kind):
         assert np.max(best - got) <= TOL
 
 
-def test_frozen_row_bit_identical_through_a_chunk(granite):
-    cfg, weights, _ = granite
+def _admitted_pair(cfg, weights):
+    """A paged decode and a 2-slot state, both slots admitted."""
     dec = build_decode(cfg, LT.LayoutSpec("paged", PAGE, 24),
                        prefill_chunk=8)
     state = dec.init_state(2, 48)
@@ -172,11 +173,22 @@ def test_frozen_row_bit_identical_through_a_chunk(granite):
         _, state, _ = dec.prefill_into_slot_chunked(
             weights, state, np.int32(s), rng.randint(1, 512, size=11),
             chunk=8)
-    before = state.merged()
+    return dec, state
+
+
+def _chunk_slot_1_frozen(dec, weights, state):
     toks, after, _ = decode_chunk(
         dec, weights, state, jnp.asarray([3, 5], jnp.int32),
         jax.random.PRNGKey(0), jnp.zeros((2,)), jnp.asarray([True, False]),
         4)
+    return toks, after
+
+
+def test_frozen_row_bit_identical_through_a_chunk(granite):
+    cfg, weights, _ = granite
+    dec, state = _admitted_pair(cfg, weights)
+    before = state.merged()
+    toks, after = _chunk_slot_1_frozen(dec, weights, state)
     after = after.merged()
     for f in ("k", "v", "ssm", "conv", "len"):
         ax = state.axes[f]
@@ -186,6 +198,80 @@ def test_frozen_row_bit_identical_through_a_chunk(granite):
         assert not np.array_equal(np.take(np.asarray(after[f]), 0, axis=ax),
                                   np.take(np.asarray(before[f]), 0, axis=ax))
     assert np.all(np.asarray(toks[1]) == 5)
+
+
+def test_frozen_row_bit_identical_on_the_pallas_path(granite, monkeypatch):
+    """The same chunk with the Mamba-2 state stepped in place by the
+    kernel (interpret mode): the frozen slot's state comes through bit
+    for bit, the live slot's follows the XLA path to rounding."""
+    cfg, weights, _ = granite
+    dec, state = _admitted_pair(cfg, weights)
+    toks_xla, xla = _chunk_slot_1_frozen(dec, weights, state)
+    monkeypatch.setattr(runtime.flags, "use_pallas", True)
+    monkeypatch.setattr(runtime.flags, "pallas_interpret", True)
+    toks, got = _chunk_slot_1_frozen(dec, weights, state)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(toks_xla))
+    for f in ("ssm", "conv"):
+        np.testing.assert_array_equal(np.asarray(got.kv[f])[:, 1],
+                                      np.asarray(state.kv[f])[:, 1],
+                                      err_msg=f)
+        np.testing.assert_allclose(np.asarray(got.kv[f])[:, 0],
+                                   np.asarray(xla.kv[f])[:, 0],
+                                   rtol=1e-5, atol=1e-5, err_msg=f)
+
+
+def _visits(jaxpr, want, times=1):
+    """(equation, times it runs) for every equation ``want`` accepts,
+    through nested jaxprs; a scan body runs ``length`` times."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        if want(eqn):
+            yield eqn, times
+        if eqn.primitive.name == "pallas_call":
+            continue
+        n = times * eqn.params.get("length", 1)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _visits(sub, want, n)
+
+
+@pytest.mark.parametrize("pallas", [False, True])
+def test_mamba_state_steps_in_place_in_its_stack(granite, monkeypatch,
+                                                 pallas):
+    """On the Pallas path a decode step runs one ``ssd_state_update``
+    kernel per Mamba-2 layer, the state stack aliased in and out, and
+    neither slices the stack nor writes a layer back into it; the XLA
+    path does both, once a layer."""
+    cfg, weights, _ = granite
+    dec, state = _admitted_pair(cfg, weights)
+    if pallas:
+        monkeypatch.setattr(runtime.flags, "use_pallas", True)
+        monkeypatch.setattr(runtime.flags, "pallas_interpret", True)
+    stack = state.kv["ssm"].shape
+    jaxpr = jax.make_jaxpr(dec.raw_step)(
+        weights, state, jnp.asarray([3, 5], jnp.int32),
+        jnp.asarray([True, False])).jaxpr
+    n_mamba = cfg.layer_kinds.count("mamba")
+    calls = list(_visits(jaxpr, lambda e: e.primitive.name == "pallas_call"
+                         and e.params["name"] == "ssd_state_update"))
+    on_stack = list(_visits(jaxpr, lambda e: e.primitive.name in (
+        "dynamic_slice", "dynamic_update_slice")
+        and e.invars[0].aval.shape == stack))
+    if pallas:
+        assert sum(n for _, n in calls) == n_mamba
+        for eqn, _ in calls:
+            (src, dst), = eqn.params["input_output_aliases"]
+            assert eqn.invars[src].aval.shape == stack
+            assert eqn.outvars[dst].aval.shape == stack
+        assert on_stack == []
+    else:
+        assert calls == []
+        assert sorted(e.primitive.name for e, n in on_stack
+                      for _ in range(n)) == \
+            ["dynamic_slice"] * n_mamba + ["dynamic_update_slice"] * n_mamba
 
 
 @pytest.mark.parametrize("kind", ["dense", "paged"])

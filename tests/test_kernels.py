@@ -151,6 +151,55 @@ def test_ssd_chunked_matches_stepwise_recurrence():
     np.testing.assert_allclose(np.asarray(st), np.asarray(st_chk), atol=2e-4)
 
 
+# (layers, slots, heads, head_dim, state, live rows): ragged masks, several
+# rows a grid step (small states), and granite's H 64 / P 64 / N 128
+SSD_STEP_CASES = [
+    (3, 4, 4, 16, 16, (True, False, True, True)),
+    (2, 6, 2, 8, 128, (False, True, True, False, True, False)),
+    (2, 2, 64, 64, 128, (True, False)),
+    (2, 3, 4, 16, 16, None),
+]
+
+
+@pytest.mark.parametrize("L,B,H,P,N,live", SSD_STEP_CASES)
+def test_pallas_ssd_state_update_vs_step(L, B, H, P, N, live):
+    """The in-place step against ``ssd_step`` on the sliced layer plus
+    the per-row freeze: y and the stepped layer to f32 rounding, frozen
+    rows and every other layer bit-identical, one program for every
+    (traced) layer index."""
+    from repro.kernels.ssd_state_update import ssd_state_update_pallas
+    from repro.layers.common import where_rows
+    from repro.layers.ssm import ssd_step
+    ks = jax.random.split(KEY, 6)
+    stack = jax.random.normal(ks[0], (L, B, H, P, N))
+    x = jax.random.normal(ks[1], (B, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (B, H)))
+    a = -jnp.exp(jax.random.normal(ks[3], (H,)))
+    b = jax.random.normal(ks[4], (B, N))
+    c = jax.random.normal(ks[5], (B, N))
+    rows = None if live is None else jnp.asarray(live)
+    dec = jnp.exp(dt * a[None])
+    xdt = x * dt[..., None]
+    step = jax.jit(lambda s, i: ssd_state_update_pallas(
+        s, i, dec, xdt, b, c, rows, interpret=True))
+    frozen = np.flatnonzero(~np.asarray(rows)) if live is not None else []
+    for i in (L - 1, 0):
+        y, out = step(stack, jnp.int32(i))
+        y_ref, new = ssd_step(stack[i], x, dt, a, b, c)
+        if rows is not None:
+            new = where_rows(rows, new, stack[i], 0)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(new),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(out[i])[frozen],
+                                      np.asarray(stack[i])[frozen])
+        for j in set(range(L)) - {i}:
+            np.testing.assert_array_equal(np.asarray(out[j]),
+                                          np.asarray(stack[j]))
+    assert step._cache_size() == 1     # the layer index is traced
+
+
 # granite scales attention scores by 1/64 at head_dim 64, not 1/8: every
 # attention kernel takes the scale as an argument (None: D ** -0.5)
 SCALE = 0.015625

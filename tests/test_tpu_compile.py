@@ -96,3 +96,24 @@ def test_kernel_compiles_for_v5e(one_chip, arch, kernel):
     hlo = jax.jit(fn).lower(*shapes).compile().as_text()
     assert hlo.count("tpu_custom_call") == 1, \
         f"{kernel} at {arch} widths compiled without its Mosaic kernel"
+
+
+def test_ssd_state_update_compiles_for_v5e(one_chip):
+    """granite-4.0-h-micro's Mamba-2 decode step: one Mosaic kernel, the
+    donated state stack updated in place (aliased, no copy of it)."""
+    from repro.kernels.ssd_state_update import ssd_state_update_pallas
+    cfg = get_config("granite-4.0-h-micro")
+    L, B, H, P, N = 9, 4, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    x = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    f32 = jnp.float32
+    stack = x((L, B, H, P, N), f32)
+    compiled = jax.jit(ssd_state_update_pallas, donate_argnums=0).lower(
+        stack, x((), jnp.int32), x((B, H), f32), x((B, H, P), f32),
+        x((B, N), f32), x((B, N), f32), x((B,), bool)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    stack_bytes = L * B * H * P * N * 4
+    assert compiled.memory_analysis().alias_size_in_bytes == stack_bytes
+    assert f"f32[{L},{B},{H},{P},{N}]" not in "".join(
+        line for line in hlo.splitlines() if " copy(" in line)
